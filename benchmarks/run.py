@@ -86,6 +86,8 @@ def main() -> None:
                        f"{m} — {e.get('help', e['entry'])} -> "
                        f"{e['artifact']}" for m, e in sorted(MODES.items())))
     args = p.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.mode:
         e = MODES[args.mode]

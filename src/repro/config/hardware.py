@@ -96,6 +96,25 @@ PAPER_H800 = HardwareProfile("h800", 990e12, 3350 * GB, 400 * GB, 64 * GB,
 PROFILES = {p.name: p for p in
             (TPU_V5E, PAPER_A100, PAPER_A30, PAPER_4090, PAPER_L20, PAPER_H800)}
 
+# The profile of each accelerator this repo runs on, keyed by the
+# ``device_kind`` JAX reports for it.
+DEVICE_PROFILES = {"TPU v5 lite": TPU_V5E}
+
+
+def profile_for_device(device) -> HardwareProfile:
+    """The planner's hardware profile for a JAX device. An accelerator
+    must be in ``DEVICE_PROFILES``; the CPU backend (tests and local
+    rehearsals) plans for the TPU v5e target."""
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return DEVICE_PROFILES[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware profile for device kind {device.device_kind!r} "
+            f"(known: {sorted(DEVICE_PROFILES)}); add it to "
+            f"DEVICE_PROFILES or pass --profile") from None
+
 # MXU efficiency assumed for the cost model's GEMM estimates (cuBLAS/MXU
 # sustained fraction on well-shaped GEMMs).
 GEMM_EFFICIENCY = 0.65

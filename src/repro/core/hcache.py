@@ -369,8 +369,9 @@ class HCacheManager:
         adapter = self.model.adapter
         k_name, v_name = adapter.kv_names or ("k", "v")
         for li, method in enumerate(methods):
-            if kinds[li] != BlockKind.ATTENTION or method != "kv":
-                continue
+            if (kinds[li] != BlockKind.ATTENTION or method != "kv"
+                    or n_tokens <= prev_n):
+                continue        # (a one-token answer adds no KV rows)
             idx = adapter.kv_row(li)
             k = np.asarray(cache[k_name][idx][0][prev_n:n_tokens])
             v = np.asarray(cache[v_name][idx][0][prev_n:n_tokens])

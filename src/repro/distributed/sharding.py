@@ -118,20 +118,13 @@ def fsdp_rules(mesh: Mesh, **kw) -> ShardingRules:
 
 
 def current_mesh():
-    """The ambient mesh (jax>=0.5 abstract mesh, else the 0.4.x
-    thread-local physical mesh from a ``with mesh:`` context)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
+    """The ambient (abstract) mesh; empty outside a mesh context."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def mesh_context(mesh):
-    """Context manager activating ``mesh`` (jax.set_mesh on jax>=0.5;
-    on 0.4.x a Mesh is itself the context manager)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh``."""
+    return jax.set_mesh(mesh)
 
 
 def constrain(x, rules: ShardingRules, *axes: Optional[str]):

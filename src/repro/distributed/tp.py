@@ -4,10 +4,20 @@
 One ``TPContext`` describes the 1-D tensor-parallel mesh the serving
 engine shards device state over: the KV-head axis of the paged page
 pool, the KV output axis of the ``RestoreParamPack`` weight stacks, and
-the head axis of decode attention. Everything degrades to the classic
-single-device path when ``tp == 1`` or the process has fewer devices
-than requested (``spmd`` is False and every placement helper is the
-identity) — the same code path serves a laptop and a pod slice.
+the head axis of decode attention. With ``tp == 1`` there is no mesh
+(``spmd`` is False and every placement helper is the identity); a
+``tp`` wider than the visible devices is an error, never a silent
+fallback to one device.
+
+Where each array lives under ``tp > 1``:
+
+  * model parameters — replicated on every mesh device (the engine
+    commits them so), as are the hidden-state uploads and RoPE tables
+    the restore projection reads, and whatever leaves the pool for
+    unsharded code (chunked-prefill history, pause snapshots): that
+    code then runs as one replicated program over the mesh;
+  * the page pool and the projection weight stacks (wk/wv/bk/bv) —
+    sharded on the KV-head axis, 1/tp of the bytes per device.
 
 Sharding discipline (the byte-identity invariant the tests pin):
 
@@ -44,20 +54,26 @@ TP_AXIS = "model"
 class TPContext:
     """A 1-D tensor-parallel mesh over the first ``tp`` local devices.
 
-    ``spmd`` is True only when the sharded path is actually live; all
-    placement helpers are identities otherwise, so callers never branch.
+    ``spmd`` is True iff ``tp > 1``; all placement helpers are identities
+    otherwise, so callers never branch. Raises when fewer than ``tp``
+    devices are visible.
     """
 
     def __init__(self, tp: int = 1, *, axis: str = TP_AXIS):
         self.tp = max(int(tp), 1)
         self.axis = axis
-        devices = jax.devices()
-        self.spmd = self.tp > 1 and len(devices) >= self.tp
+        n_dev = len(jax.devices())
+        if self.tp > n_dev:
+            raise ValueError(
+                f"tensor-parallel width tp={self.tp} needs {self.tp} "
+                f"devices; {n_dev} visible (on the CPU, set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={self.tp} "
+                f"before jax is imported)")
+        self.spmd = self.tp > 1
         self.mesh = None
         if self.spmd:
             from repro.launch.mesh import make_mesh
             self.mesh = make_mesh((self.tp,), (axis,))
-        self.device0 = devices[0]
 
     def __repr__(self):
         return f"TPContext(tp={self.tp}, spmd={self.spmd})"
@@ -95,18 +111,13 @@ class TPContext:
         return jax.device_put(x, self.kv_sharding(x.ndim, kv_axis))
 
     def replicate(self, x):
-        """Commit ``x`` replicated across the mesh."""
+        """Commit ``x`` (an array or a pytree of them) replicated across
+        the mesh — also the seam from sharded state back into unsharded
+        code (gather_hist feeding prefill, snapshots feeding the host
+        store)."""
         if not self.spmd:
             return x
         return jax.device_put(x, self.replicated())
-
-    def unshard(self, x):
-        """Pull a (possibly sharded) array to the first device — the
-        seam back into single-device code (gather_hist feeding an
-        unsharded prefill, snapshots feeding the host store)."""
-        if not self.spmd:
-            return x
-        return jax.device_put(x, self.device0)
 
 
 # --------------------------------------------------------------- seam hooks

@@ -134,6 +134,12 @@ class EnginePump:
         self._thread.start()
         return self
 
+    @property
+    def alive(self) -> bool:
+        """False once the pump thread has exited (closed, or killed by an
+        exception from the engine)."""
+        return self._thread.is_alive()
+
     def pending(self) -> int:
         """Unfinished requests anywhere in the pipeline."""
         return len(self._inbox) + len(self._subs)

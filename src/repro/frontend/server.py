@@ -133,18 +133,31 @@ def _reason(status: int) -> str:
             500: "Internal Server Error"}.get(status, "OK")
 
 
-async def serve_engine(engine, host: str = "127.0.0.1", port: int = 8080,
-                       *, max_pending: int = 64,
-                       router: Optional[SessionRouter] = None,
-                       ready: Optional[asyncio.Event] = None):
-    """Wire pump → router → api → socket and serve until cancelled;
-    tears the stack down in reverse (socket, pump quiesce, engine.close)."""
+async def start_front_door(engine, host: str = "127.0.0.1", port: int = 0,
+                           *, max_pending: int = 64,
+                           router: Optional[SessionRouter] = None):
+    """Wire pump → router → api → socket and start listening. Returns
+    ``(pump, server)``; the caller shuts down in reverse:
+    ``await server.close()``, then ``pump.close()`` (which quiesces the
+    pump and closes the engine)."""
     pump = EnginePump(engine, max_pending=max_pending).start()
     api = FrontDoor(pump, router)
     srv = HttpFrontDoor(api, host, port)
     await srv.start()
+    return pump, srv
+
+
+async def serve_engine(engine, host: str = "127.0.0.1", port: int = 8080,
+                       *, max_pending: int = 64,
+                       router: Optional[SessionRouter] = None,
+                       ready: Optional[asyncio.Event] = None):
+    """Serve the engine through the front door until cancelled; tears
+    the stack down in reverse (socket, pump quiesce, engine.close)."""
+    pump, srv = await start_front_door(engine, host, port,
+                                       max_pending=max_pending,
+                                       router=router)
     print(f"front door listening on http://{host}:{srv.port} "
-          f"(model {api.model_name})", flush=True)
+          f"(model {srv.api.model_name})", flush=True)
     if ready is not None:
         ready.set()
     try:

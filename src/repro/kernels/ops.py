@@ -13,8 +13,7 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import (decode_attention_paged_pallas,
                                             decode_attention_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.restore_kv import (restore_kv_grouped_pallas,
-                                      restore_kv_pallas)
+from repro.kernels.restore_kv import restore_kv_grouped_pallas
 from repro.kernels.ssm_update import ssm_update_pallas
 
 
@@ -27,10 +26,14 @@ def restore_kv(hidden, wk, wv, bk, bv, cos, sin, *, head_dim,
     if not use_pallas:
         return ref.restore_kv_ref(hidden, wk, wv, bk, bv, cos, sin,
                                   head_dim=head_dim, use_rope=use_rope)
+    # one layer is a group of one
     interpret = (not on_tpu()) if interpret is None else interpret
-    return restore_kv_pallas(hidden, wk, wv, bk, bv, cos, sin,
-                             head_dim=head_dim, use_rope=use_rope,
-                             interpret=interpret)
+    k, v = restore_kv_grouped_pallas(
+        hidden[None], wk[None], wv[None],
+        None if bk is None else bk[None], None if bv is None else bv[None],
+        cos, sin, head_dim=head_dim, use_rope=use_rope,
+        interpret=interpret)
+    return k[0], v[0]
 
 
 def restore_kv_grouped(hidden, wk, wv, bk, bv, cos, sin, *, head_dim,
@@ -41,10 +44,10 @@ def restore_kv_grouped(hidden, wk, wv, bk, bv, cos, sin, *, head_dim,
     executor in core/restoration.py).
 
     ``kv_sharding`` (NamedSharding over the flattened KV axis of the
-    (G, S, KV) outputs, DESIGN.md §16) constrains the results so the
-    SPMD partitioner keeps each device's projected heads local — the
-    restore sink then scatters them into a same-sharded page pool with
-    zero cross-device traffic."""
+    (G, S, KV) outputs, DESIGN.md §16) keeps each device's projected
+    heads local — the kernel runs under ``shard_map``, the jnp oracle
+    under a sharding constraint — so the restore sink scatters them into
+    a same-sharded page pool with zero cross-device traffic."""
     if not use_pallas:
         k, v = ref.restore_kv_grouped_ref(hidden, wk, wv, bk, bv, cos, sin,
                                           head_dim=head_dim,

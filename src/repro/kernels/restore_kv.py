@@ -1,22 +1,44 @@
 """Pallas TPU kernel: fused HCache restoration — K/V projection + RoPE.
 
 The paper issues a cuBLAS GEMM then a separate RoPE+copy kernel (§5). On
-TPU we fuse: each grid cell loads one hidden-state tile into VMEM once,
-produces MXU-native K and V tiles, applies the rotary transform to K
-in-register, and writes both outputs — one pass over HBM for H, no
+TPU we fuse: each grid cell loads one hidden-state tile into VMEM, feeds
+it to the MXU as bf16 with f32 accumulation, adds the bias, rotates K
+per head and writes both outputs — one pass over HBM for H, no
 intermediate K buffer.
 
-Tiling: grid = (S / BLOCK_S, KV / BLOCK_KV). The full contraction dim (D)
-is kept resident per cell: worst assigned arch D=6144 → H tile
-256×6144×2B = 3 MiB + two 6144×BLOCK_KV weight tiles ≈ 3 MiB < VMEM.
-BLOCK_KV must cover whole heads (multiple of head_dim) so the rotate-half
-pairing stays in-tile; MXU alignment wants multiples of 128.
+One launch projects a *group* of layers: grid = (G, S / BLOCK_S,
+KV / BLOCK_KV), the leading dimension indexes a stack of per-layer
+weights, so the serving-path executor coalesces ready projection tasks
+into one call instead of L per-layer dispatches (core/restoration.py).
+The KV axis is innermost, so the hidden tile's block index is unchanged
+across it and the pipeline fetches each hidden tile once per (g, i).
 
-``restore_kv_grouped_pallas`` is the batched-restoration variant: a
-leading grid dimension G indexes a stack of per-layer weights, so one
-kernel launch projects ``group_size`` layers' hidden states — the
-serving-path executor coalesces ready projection tasks into one such
-call instead of L per-layer dispatches (see core/restoration.py).
+VMEM. The contraction dim D stays resident. Every input and output
+block is double-buffered by the pipeline, so a cell holds
+
+    2 · (BS·D + 2·D·BKV) · itemsize        hidden + wk + wv tiles
+  + 2 · 2 · BS·BKV · itemsize              k, v output tiles
+  + 2 · 2 · BS·hd · 4                      f32 cos / signed-sin tiles
+  + 2 · 2 · BKV · itemsize                 biases (when present)
+  + 2 · BS·BKV · 4                         f32 k, v accumulators
+
+``_pick_blocks`` takes the tallest BLOCK_S, then the widest BLOCK_KV
+(whole heads), that keep this under ``VMEM_BUDGET``, 3/4 of the 16 MiB
+default scoped VMEM limit of a v5e core (the remainder is headroom for
+the compiler's own temporaries). qwen2-7b (D=3584, KV=512, bf16) gets
+256×128 tiles (8.0 MiB); llama2-7b (D=4096, KV=4096) 256×128
+(9.0 MiB).
+
+RoPE is the rotate-half form applied per head as
+``x · [cos, cos] + roll(x, hd/2) · [-sin, sin]``: a lane rotation of one
+head-wide slice instead of a lane-splitting reshape and a half-width
+concat, and elementwise the same f32 arithmetic as ``ref.py``.
+
+Under tensor parallelism (``kv_sharding``) the launch is wrapped in a
+``shard_map`` over the mesh: each device runs the kernel on its own
+slice of the KV axis (whole heads, since tp | n_kv_heads), with hidden
+states and RoPE tables replicated — Mosaic kernels are never
+partitioned by the SPMD partitioner.
 """
 from __future__ import annotations
 
@@ -25,113 +47,125 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+VMEM_BUDGET = 12 * 1024 * 1024
 
 
-def _pick_block_kv(KV: int, head_dim: int, block_kv: int) -> int:
-    """Largest tile ≤ ``block_kv`` that divides KV *and* covers whole
-    heads. Halving blindly (the old fallback) can drop below head_dim
-    for non-power-of-two widths (KV=960, head_dim=96 → 64), splitting a
-    head across tiles and silently corrupting the rotate-half pairing —
-    so the search walks multiples of head_dim instead. head_dim always
-    divides KV (KV = n_kv_heads · head_dim), so ≥ head_dim is reachable."""
-    block_kv = block_kv or max(head_dim, min(KV, 512))
+def _vmem_bytes(block_s: int, block_kv: int, D: int, head_dim: int,
+                itemsize: int, has_bias: bool) -> int:
+    """Bytes of VMEM one grid cell holds (see the module docstring)."""
+    ins = (block_s * D + 2 * D * block_kv) * itemsize \
+        + 2 * block_s * head_dim * 4 \
+        + (2 * block_kv * itemsize if has_bias else 0)
+    outs = 2 * block_s * block_kv * itemsize
+    return 2 * (ins + outs) + 2 * block_s * block_kv * 4
+
+
+def _pick_blocks(S: int, KV: int, D: int, head_dim: int, itemsize: int,
+                 has_bias: bool, block_s: int, block_kv: int):
+    """(BLOCK_S, BLOCK_KV): BLOCK_S a divisor of S, BLOCK_KV a divisor
+    of KV that covers whole heads (a head split across tiles would break
+    the rotate-half pairing). The tallest BLOCK_S, then the widest
+    BLOCK_KV, within ``VMEM_BUDGET``; ``block_s``/``block_kv`` cap the
+    search. When nothing fits, the smallest tiles are returned."""
     n_heads = KV // head_dim
-    bh = max(block_kv // head_dim, 1)
-    while n_heads % bh:
-        bh -= 1
-    return bh * head_dim
+    cap_kv = block_kv or KV
+    kv_opts = [h * head_dim for h in range(n_heads, 0, -1)
+               if n_heads % h == 0 and h * head_dim <= max(cap_kv, head_dim)]
+    s_opts = []
+    b = min(block_s, S)
+    while b >= 1:
+        if S % b == 0:
+            s_opts.append(b)
+        b //= 2
+    s_opts = s_opts or [S]
+    for bs in s_opts:
+        for bkv in kv_opts:
+            if _vmem_bytes(bs, bkv, D, head_dim, itemsize,
+                           has_bias) <= VMEM_BUDGET:
+                return bs, bkv
+    return s_opts[-1], kv_opts[-1]
 
 
-def _pick_block_s(S: int, block_s: int) -> int:
-    block_s = min(block_s, S)
-    while S % block_s:
-        block_s //= 2
-    return block_s
+def _rope_tables(cos, sin):
+    """(S, hd/2) cos/sin -> (S, hd) [cos, cos] and [-sin, sin] in f32."""
+    cos = cos.astype(jnp.float32)
+    sin = sin.astype(jnp.float32)
+    return (jnp.concatenate([cos, cos], axis=-1),
+            jnp.concatenate([-sin, sin], axis=-1))
 
 
-def _rope_rotate(x, cos, sin, head_dim: int):
-    """x: (BS, BKV) covering whole heads; rotate each head's halves."""
-    bs, bkv = x.shape
-    n_heads = bkv // head_dim
-    xh = x.reshape(bs, n_heads, head_dim)
-    x1 = xh[..., : head_dim // 2]
-    x2 = xh[..., head_dim // 2:]
-    rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
-    return rot.reshape(bs, bkv)
-
-
-def _restore_kv_kernel(h_ref, wk_ref, wv_ref, bk_ref, bv_ref, cos_ref,
-                       sin_ref, k_ref, v_ref, *, head_dim: int,
-                       use_rope: bool):
-    h = h_ref[...].astype(jnp.float32)
-    k = jax.lax.dot(h, wk_ref[...].astype(jnp.float32),
-                    precision=jax.lax.Precision.DEFAULT)
-    v = jax.lax.dot(h, wv_ref[...].astype(jnp.float32),
-                    precision=jax.lax.Precision.DEFAULT)
-    if bk_ref is not None:
+def _restore_kv_kernel(*refs, head_dim: int, use_rope: bool,
+                       has_bias: bool):
+    if has_bias:
+        (h_ref, wk_ref, wv_ref, bk_ref, bv_ref, cos_ref, sin_ref,
+         k_ref, v_ref) = refs
+    else:
+        h_ref, wk_ref, wv_ref, cos_ref, sin_ref, k_ref, v_ref = refs
+    h = h_ref[...]
+    k = jax.lax.dot(h, wk_ref[...], preferred_element_type=jnp.float32)
+    v = jax.lax.dot(h, wv_ref[...], preferred_element_type=jnp.float32)
+    if has_bias:
         k = k + bk_ref[...].astype(jnp.float32)
         v = v + bv_ref[...].astype(jnp.float32)
-    if use_rope:
-        cos = cos_ref[...][:, None, :]          # (BS, 1, hd/2)
-        sin = sin_ref[...][:, None, :]
-        k = _rope_rotate(k, cos, sin, head_dim)
-    k_ref[...] = k.astype(k_ref.dtype)
     v_ref[...] = v.astype(v_ref.dtype)
+    if not use_rope:
+        k_ref[...] = k.astype(k_ref.dtype)
+        return
+    cos = cos_ref[...]
+    sin = sin_ref[...]
+    for j in range(k.shape[1] // head_dim):
+        sl = slice(j * head_dim, (j + 1) * head_dim)
+        x = k[:, sl]
+        rolled = pltpu.roll(x, shift=head_dim // 2, axis=1)
+        k_ref[:, sl] = (x * cos + rolled * sin).astype(k_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("head_dim", "use_rope",
-                                             "block_s", "block_kv",
-                                             "interpret"))
-def restore_kv_pallas(hidden, wk, wv, bk, bv, cos, sin, *, head_dim: int,
-                      use_rope: bool = True, block_s: int = 256,
-                      block_kv: int = 0, interpret: bool = True):
-    """hidden (S, D); wk/wv (D, KV); bk/bv (KV,) or None;
-    cos/sin (S, head_dim//2). Returns K, V: (S, KV) (K rotated)."""
-    S, D = hidden.shape
-    KV = wk.shape[1]
-    block_kv = _pick_block_kv(KV, head_dim, block_kv)
-    block_s = _pick_block_s(S, block_s)
-    grid = (S // block_s, KV // block_kv)
-
+def _grouped_call(hidden, wk, wv, bk, bv, cos2, sin2, *, head_dim,
+                  use_rope, block_s, block_kv, interpret):
+    """The pallas_call over (possibly per-device) arrays: hidden
+    (G, S, D); wk/wv (G, D, KV); bk/bv (G, 1, KV) or None; cos2/sin2
+    (S, head_dim) f32."""
+    G, S, D = hidden.shape
+    KV = wk.shape[2]
     has_bias = bk is not None
+    block_s, block_kv = _pick_blocks(S, KV, D, head_dim,
+                                     jnp.dtype(hidden.dtype).itemsize,
+                                     has_bias, block_s, block_kv)
+    grid = (G, S // block_s, KV // block_kv)
+    # leading None squeezes the group dim out of the per-cell refs
     in_specs = [
-        pl.BlockSpec((block_s, D), lambda i, j: (i, 0)),          # hidden
-        pl.BlockSpec((D, block_kv), lambda i, j: (0, j)),         # wk
-        pl.BlockSpec((D, block_kv), lambda i, j: (0, j)),         # wv
+        pl.BlockSpec((None, block_s, D), lambda g, i, j: (g, i, 0)),
+        pl.BlockSpec((None, D, block_kv), lambda g, i, j: (g, 0, j)),
+        pl.BlockSpec((None, D, block_kv), lambda g, i, j: (g, 0, j)),
     ]
     args = [hidden, wk, wv]
     if has_bias:
-        in_specs += [pl.BlockSpec((block_kv,), lambda i, j: (j,)),
-                     pl.BlockSpec((block_kv,), lambda i, j: (j,))]
+        # (G, 1, KV): the block's last two dims (1, BKV) are (full dim,
+        # lane multiple) — a squeezed (BKV,) row is not a legal TPU block
+        in_specs += [pl.BlockSpec((None, 1, block_kv),
+                                  lambda g, i, j: (g, 0, j))] * 2
         args += [bk, bv]
-    in_specs += [pl.BlockSpec((block_s, head_dim // 2), lambda i, j: (i, 0)),
-                 pl.BlockSpec((block_s, head_dim // 2), lambda i, j: (i, 0))]
-    args += [cos, sin]
-
-    kernel = functools.partial(
-        _restore_kv_kernel if has_bias else _no_bias_kernel,
-        head_dim=head_dim, use_rope=use_rope)
-    out = pl.pallas_call(
+    in_specs += [pl.BlockSpec((block_s, head_dim),
+                              lambda g, i, j: (i, 0))] * 2
+    args += [cos2, sin2]
+    out_spec = pl.BlockSpec((None, block_s, block_kv),
+                            lambda g, i, j: (g, i, j))
+    kernel = functools.partial(_restore_kv_kernel, head_dim=head_dim,
+                               use_rope=use_rope, has_bias=has_bias)
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((block_s, block_kv), lambda i, j: (i, j)),
-                   pl.BlockSpec((block_s, block_kv), lambda i, j: (i, j))],
-        out_shape=[jax.ShapeDtypeStruct((S, KV), hidden.dtype),
-                   jax.ShapeDtypeStruct((S, KV), hidden.dtype)],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((G, S, KV), hidden.dtype)] * 2,
         interpret=interpret,
+        name="restore_kv_grouped",
     )(*args)
-    return out
 
 
-def _no_bias_kernel(h_ref, wk_ref, wv_ref, cos_ref, sin_ref, k_ref, v_ref,
-                    *, head_dim: int, use_rope: bool):
-    _restore_kv_kernel(h_ref, wk_ref, wv_ref, None, None, cos_ref, sin_ref,
-                       k_ref, v_ref, head_dim=head_dim, use_rope=use_rope)
-
-
-# ------------------------------------------------------- grouped variant
 @functools.partial(jax.jit, static_argnames=("head_dim", "use_rope",
                                              "block_s", "block_kv",
                                              "interpret", "kv_sharding"))
@@ -143,65 +177,25 @@ def restore_kv_grouped_pallas(hidden, wk, wv, bk, bv, cos, sin, *,
 
     hidden (G, S, D); wk/wv (G, D, KV); bk/bv (G, KV) or None; cos/sin
     (S, head_dim//2) shared by all group members (same positions).
-    Returns K, V: (G, S, KV). One launch instead of G — grid gains a
-    leading group dimension that indexes the weight stack, and each
-    (g, i, j) cell is exactly the per-layer kernel's (i, j) cell for
-    layer g; the per-cell bodies are shared with the per-layer kernel.
+    Returns K, V: (G, S, KV), K rotated.
 
-    ``kv_sharding`` (static NamedSharding on the KV output axis) pins
-    the outputs sharded over a tensor-parallel mesh — with the weight
-    stacks committed KV-sharded the grid's j dimension partitions across
-    devices and each device runs only its own heads' tiles
-    (DESIGN.md §16). The KV tile never spans a shard boundary because
-    both the shard size and the tile cover whole heads."""
-    G, S, D = hidden.shape
-    KV = wk.shape[2]
-    block_kv = _pick_block_kv(KV, head_dim, block_kv)
-    if kv_sharding is not None:
-        # a tile must not straddle the per-device KV slice: cap it at
-        # the shard width (whole heads by construction — validate_heads
-        # guarantees tp | n_kv_heads)
-        n_shards = kv_sharding.mesh.size
-        block_kv = min(block_kv, _pick_block_kv(KV // n_shards, head_dim,
-                                                block_kv))
-    block_s = _pick_block_s(S, block_s)
-    grid = (G, S // block_s, KV // block_kv)
-
-    has_bias = bk is not None
-    # leading None squeezes the group dim out of the per-cell refs, so
-    # the kernel bodies stay rank-2 (shared with the per-layer variant)
-    in_specs = [
-        pl.BlockSpec((None, block_s, D), lambda g, i, j: (g, i, 0)),
-        pl.BlockSpec((None, D, block_kv), lambda g, i, j: (g, 0, j)),
-        pl.BlockSpec((None, D, block_kv), lambda g, i, j: (g, 0, j)),
-    ]
-    args = [hidden, wk, wv]
-    if has_bias:
-        in_specs += [pl.BlockSpec((None, block_kv), lambda g, i, j: (g, j)),
-                     pl.BlockSpec((None, block_kv), lambda g, i, j: (g, j))]
-        args += [bk, bv]
-    in_specs += [pl.BlockSpec((block_s, head_dim // 2),
-                              lambda g, i, j: (i, 0)),
-                 pl.BlockSpec((block_s, head_dim // 2),
-                              lambda g, i, j: (i, 0))]
-    args += [cos, sin]
-
-    kernel = functools.partial(
-        _restore_kv_kernel if has_bias else _no_bias_kernel,
-        head_dim=head_dim, use_rope=use_rope)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((None, block_s, block_kv),
-                                lambda g, i, j: (g, i, j)),
-                   pl.BlockSpec((None, block_s, block_kv),
-                                lambda g, i, j: (g, i, j))],
-        out_shape=[jax.ShapeDtypeStruct((G, S, KV), hidden.dtype),
-                   jax.ShapeDtypeStruct((G, S, KV), hidden.dtype)],
-        interpret=interpret,
-    )(*args)
-    if kv_sharding is not None:
-        out = [jax.lax.with_sharding_constraint(o, kv_sharding)
-               for o in out]
-    return out
+    ``kv_sharding`` (static NamedSharding placing a mesh axis on the KV
+    output axis) runs the kernel under ``shard_map``: each device
+    projects only its own heads from its slice of the weight stacks,
+    and the outputs come back KV-sharded for the shard-local page-pool
+    scatter (DESIGN.md §16)."""
+    G = hidden.shape[0]
+    cos2, sin2 = _rope_tables(cos, sin)
+    if bk is not None:
+        bk = bk.reshape(G, 1, -1)
+        bv = bv.reshape(G, 1, -1)
+    call = functools.partial(_grouped_call, head_dim=head_dim,
+                             use_rope=use_rope, block_s=block_s,
+                             block_kv=block_kv, interpret=interpret)
+    if kv_sharding is None:
+        return call(hidden, wk, wv, bk, bv, cos2, sin2)
+    kv, rep = P(None, None, kv_sharding.spec[2]), P()
+    return jax.shard_map(call, mesh=kv_sharding.mesh,
+                         in_specs=(rep, kv, kv, kv, kv, rep, rep),
+                         out_specs=(kv, kv), check_vma=False)(
+        hidden, wk, wv, bk, bv, cos2, sin2)

@@ -14,16 +14,10 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-
-try:  # jax >= 0.5 — explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: no AxisType; meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

@@ -1,8 +1,12 @@
 """Serving driver: HCache-enabled engine over a synthetic conversation
-trace (CPU-runnable with reduced configs).
+trace, or behind the OpenAI-compatible front door (``--serve-http``).
+Reduced configs unless ``--full``; bf16 with random weights.
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b \
         --sessions 4 --rounds 2
+
+``build_engine`` is the one construction path: ``chip_smoke.py`` builds
+its engine through it too.
 """
 from __future__ import annotations
 
@@ -13,13 +17,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config.arch import reduced_for_smoke
-from repro.config.hardware import PROFILES
+from repro.config.hardware import PROFILES, profile_for_device
 from repro.configs import get_arch
 from repro.core.capacity import (ADMISSION_POLICIES, CapacityManager,
                                  EVICTION_POLICIES,
                                  RestoreCostAwareAdmission)
 from repro.core.hcache import HCacheManager
 from repro.distributed.sharding import default_rules
+from repro.distributed.tp import TPContext
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.models.module import split
@@ -29,7 +35,7 @@ from repro.storage import (AsyncIOEngine, ChunkStore, make_array,
                            make_shards)
 
 
-def main() -> None:
+def make_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="llama2-7b")
     p.add_argument("--sessions", type=int, default=3)
@@ -38,7 +44,10 @@ def main() -> None:
     p.add_argument("--gen", type=int, default=8)
     p.add_argument("--max-batch", type=int, default=4)
     p.add_argument("--max-seq", type=int, default=256)
-    p.add_argument("--profile", default="a100", choices=sorted(PROFILES))
+    p.add_argument("--profile", default=None, choices=sorted(PROFILES),
+                   help="hardware profile the restore planner prices "
+                        "with (default: the device's own, looked up by "
+                        "device_kind in config/hardware.py)")
     p.add_argument("--ssds", type=int, default=4)
     p.add_argument("--hosts", type=int, default=1,
                    help="distributed store: number of host shards, each "
@@ -75,8 +84,8 @@ def main() -> None:
     p.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel width: shard the paged KV pool "
                         "and the restoration projection over this many "
-                        "devices (KV-head axis; falls back to 1 when the "
-                        "host exposes fewer devices — set XLA_FLAGS="
+                        "devices (KV-head axis; an error when fewer are "
+                        "visible — set XLA_FLAGS="
                         "--xla_force_host_platform_device_count=N on CPU)")
     p.add_argument("--cache-blocks", type=int, default=None,
                    help="paged backend: physical pages in the pool "
@@ -126,19 +135,41 @@ def main() -> None:
                    help="synthetic trace: session s gets priority "
                         "s %% N (exercises --admission priority; 1 = all "
                         "equal)")
-    args = p.parse_args()
+    return p
+
+
+def init_params(model: Model, seed: int, sharding=None):
+    """Random weights from ``seed``, initialized inside one jitted
+    program so only the model dtype reaches device memory (an eager init
+    materializes each stacked weight in f32 first). ``sharding`` places
+    them directly (replicated over a tensor-parallel mesh)."""
+    kw = {} if sharding is None else {"out_shardings": sharding}
+    init = jax.jit(lambda key: split(model.init(key))[0], **kw)
+    return init(jax.random.PRNGKey(seed))
+
+
+def build_engine(args, cfg=None, seed: int = 0) -> InferenceEngine:
+    """The serving stack from parsed ``make_parser`` options: bf16 model
+    with random weights from ``seed``, host chunk store, HCache manager
+    and engine. ``cfg`` overrides ``--arch``/``--full``. The store, the
+    measured profile and the capacity manager are reachable from the
+    engine (``engine.mgr.store``, ``engine.mgr.profile``,
+    ``engine.capacity``)."""
     group_size = (args.restore_group_size
                   if args.restore_group_size in ("auto", "fetch")
                   else int(args.restore_group_size))
-
     mesh = make_mesh((1, 1), ("data", "model"))
     rules = default_rules(mesh)
-    cfg = get_arch(args.arch)
-    if not args.full:
-        cfg = reduced_for_smoke(cfg)
-    model = Model(cfg, rules=rules, model_axis=1, dtype=jnp.float32,
+    if cfg is None:
+        cfg = get_arch(args.arch)
+        if not args.full:
+            cfg = reduced_for_smoke(cfg)
+    model = Model(cfg, rules=rules, model_axis=1, dtype=jnp.bfloat16,
                   remat="none")
-    params, _ = split(model.init(jax.random.PRNGKey(0)))
+    tp = TPContext(args.tp)              # raises when devices are short
+    params = init_params(model, seed, tp.replicated())
+    hw = (PROFILES[args.profile] if args.profile
+          else profile_for_device(jax.devices()[0]))
     cold = make_array("dram", args.ssds) if args.budget_kb else None
     if args.hosts > 1:
         from repro.config.hardware import NIC_BW
@@ -161,28 +192,33 @@ def main() -> None:
         measured = (MeasuredProfile.load(args.hw_profile)
                     if os.path.exists(args.hw_profile)
                     else MeasuredProfile())
-    mgr = HCacheManager(model, store, hw=PROFILES[args.profile],
+    mgr = HCacheManager(model, store, hw=hw,
                         restore_group_size=group_size, profile=measured)
     capacity = (CapacityManager(mgr, host_budget_bytes=args.budget_kb * 1024)
                 if args.budget_kb else None)
     admission = (RestoreCostAwareAdmission(aging=args.admission_aging)
                  if args.admission == "restore_cost"
                  else ADMISSION_POLICIES[args.admission]())
-    engine = InferenceEngine(model, params, mgr, max_batch=args.max_batch,
-                             max_seq=args.max_seq,
-                             preempt_quantum=args.preempt_quantum,
-                             eviction=EVICTION_POLICIES[args.eviction](),
-                             admission=admission,
-                             capacity=capacity,
-                             backend=args.backend,
-                             block_size=args.block_size,
-                             cache_blocks=args.cache_blocks,
-                             enc_seq=args.enc_seq,
-                             prefix_sharing=args.prefix_sharing,
-                             tp=args.tp)
-    if args.tp > 1 and not engine.tp.spmd:
-        print(f"tp={args.tp} requested but only {len(jax.devices())} "
-              f"device(s) visible — running single-device")
+    return InferenceEngine(model, params, mgr, max_batch=args.max_batch,
+                           max_seq=args.max_seq,
+                           preempt_quantum=args.preempt_quantum,
+                           eviction=EVICTION_POLICIES[args.eviction](),
+                           admission=admission,
+                           capacity=capacity,
+                           backend=args.backend,
+                           block_size=args.block_size,
+                           cache_blocks=args.cache_blocks,
+                           enc_seq=args.enc_seq,
+                           prefix_sharing=args.prefix_sharing,
+                           tp=args.tp)
+
+
+def main() -> None:
+    args = make_parser().parse_args()
+    enable_compile_cache()
+    engine = build_engine(args)
+    model, store = engine.model, engine.mgr.store
+    cfg, measured, capacity = model.cfg, engine.mgr.profile, engine.capacity
 
     if args.serve_http:
         import asyncio

@@ -50,13 +50,18 @@ def embed_tokens(p: dict, ids, rules: ShardingRules, *, scale: bool,
 
 def logits(p: dict, x, rules: ShardingRules, *,
            softcap: Optional[float] = None, true_vocab: Optional[int] = None):
+    # f32 logits whatever the model dtype: bf16 keeps 8 significant bits,
+    # so over a 150k vocabulary the top logits tie and greedy sampling
+    # turns on rounding noise
     if "unembed" in p:
-        out = jnp.einsum("bsd,dv->bsv", x, p["unembed"])
+        out = jnp.einsum("bsd,dv->bsv", x, p["unembed"],
+                         preferred_element_type=jnp.float32)
     else:
-        out = jnp.einsum("bsd,vd->bsv", x, p["table"])
+        out = jnp.einsum("bsd,vd->bsv", x, p["table"],
+                         preferred_element_type=jnp.float32)
     out = constrain(out, rules, "batch", "seq", "vocab")
     if softcap is not None:
-        out = (jnp.tanh(out.astype(jnp.float32) / softcap) * softcap)
+        out = jnp.tanh(out / softcap) * softcap
     vp = out.shape[-1]
     if true_vocab is not None and vp != true_vocab:
         # padded vocab columns must never win softmax/argmax

@@ -230,7 +230,6 @@ class InferenceEngine:
         # cache mapping, resume support, save naming) goes through the
         # FamilyAdapter seam — the engine itself is family-agnostic
         self.adapter = model.adapter
-        self.params = params
         self.mgr = manager
         self.max_batch = max_batch
         self.max_seq = max_seq
@@ -250,10 +249,12 @@ class InferenceEngine:
 
         # tensor-parallel context (DESIGN.md §16): a paged lm backend
         # shards its page pool over the mesh and the manager prices /
-        # shards its restoration packs the same way. tp falls back to
-        # single-device when the host exposes fewer devices (spmd False
-        # keeps every seam an identity — one code path).
+        # shards its restoration packs the same way; the parameters are
+        # replicated on every mesh device (a no-op when the caller
+        # already initialized them there, or at tp=1). A tp wider than
+        # the visible devices raises.
         self.tp = tp_lib.TPContext(tp)
+        self.params = self.tp.replicate(params)
         set_tp = getattr(manager, "set_tp", None)
         if set_tp is not None:
             set_tp(self.tp)

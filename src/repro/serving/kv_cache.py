@@ -616,9 +616,9 @@ class _PagedView(CacheView):
 
     def gather_hist(self, hist):
         # _finish_gather is the sharded backend's seam back into
-        # single-device code: the gathered history feeds the unsharded
-        # prefill program, so it must leave the mesh here (identity on
-        # the single-device backend)
+        # unsharded code: the gathered history feeds the replicated
+        # prefill program, so its head shards are replicated here
+        # (identity on the single-device backend)
         b = self.b
         nb = -(-hist // b.block_size)
         blocks = jnp.asarray(b.table_np[self.slot][:nb])
@@ -710,16 +710,15 @@ class PagedBackend(KVCacheBackend):
         self.cache["block_table"] = jnp.asarray(self.table_np)
 
     def _finish_gather(self, x):
-        """Seam for host-bound / single-device consumers of pool gathers
+        """Seam for host-bound / unsharded consumers of pool gathers
         (chunked-prefill history, pause snapshots). Identity here; the
-        sharded backend collects the head shards onto one device."""
+        sharded backend replicates the head shards across the mesh."""
         return x
 
     def _place_kv(self, val, kv_axis: int):
         """Placement seam for values entering the pool. Identity here;
         the sharded backend reshards them to the pool's head sharding —
-        prefill KV arrives committed to the prefill device and a
-        committed single-device array cannot join a multi-device scatter
+        prefill KV arrives replicated from the replicated prefill program
         (restored KV from the SPMD projection is already head-sharded,
         so its device_put is a no-op)."""
         return val
@@ -942,7 +941,7 @@ class ShardedPagedBackend(PagedBackend):
             jnp.asarray(lengths, jnp.int32))
 
     def _finish_gather(self, x):
-        return self.tp.unshard(x)
+        return self.tp.replicate(x)
 
     def _place_kv(self, val, kv_axis):
         return self.tp.shard_kv(val, kv_axis)

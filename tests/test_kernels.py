@@ -43,8 +43,8 @@ def test_restore_kv_non_pow2_head_dim(S, D, Kv, hd):
     blindly (KV=960 → 64 < head_dim=96), splitting a head across tiles
     and corrupting the rotate-half pairing. The fallback must stay a
     multiple of head_dim."""
-    from repro.kernels.restore_kv import _pick_block_kv
-    bkv = _pick_block_kv(Kv * hd, hd, 0)
+    from repro.kernels.restore_kv import _pick_blocks
+    _, bkv = _pick_blocks(S, Kv * hd, D, hd, 4, False, 256, 512)
     assert bkv % hd == 0 and (Kv * hd) % bkv == 0
     h = jnp.asarray(RNG.normal(size=(S, D)), jnp.float32)
     wk = jnp.asarray(RNG.normal(size=(D, Kv * hd)) * D ** -0.5, jnp.float32)
@@ -90,6 +90,70 @@ def test_restore_kv_grouped_sweep(G, S, D, Kv, hd, dtype, bias):
                 np.testing.assert_allclose(
                     np.asarray(got_part[g], np.float32),
                     np.asarray(want_part, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_restore_kv_grouped_biased_multi_tile(dtype):
+    """qwen2-7b widths (D=3584, 4 KV heads of 128, QKV bias): the VMEM
+    budget splits KV=512 into 128-wide tiles, so every bias block is a
+    (1, 128) slice at a nonzero lane offset of the (G, 1, KV) stack."""
+    from repro.kernels.restore_kv import _pick_blocks
+    G, S, D, Kv, hd = 2, 256, 3584, 4, 128
+    KV = Kv * hd
+    _, bkv = _pick_blocks(S, KV, D, hd, 2, True, 256, 0)
+    assert bkv < KV
+    h = jnp.asarray(RNG.normal(size=(G, S, D)), dtype)
+    wk = jnp.asarray(RNG.normal(size=(G, D, KV)) * D ** -0.5, dtype)
+    wv = jnp.asarray(RNG.normal(size=(G, D, KV)) * D ** -0.5, dtype)
+    bk = jnp.asarray(RNG.normal(size=(G, KV)), dtype)
+    bv = jnp.asarray(RNG.normal(size=(G, KV)), dtype)
+    ang = (jnp.arange(S, dtype=jnp.float32)[:, None]
+           * 1e6 ** (-jnp.arange(hd // 2) / (hd // 2)))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    got = ops.restore_kv_grouped(h, wk, wv, bk, bv, cos, sin, head_dim=hd,
+                                 use_pallas=True)
+    want = ref.restore_kv_grouped_ref(h, wk, wv, bk, bv, cos, sin,
+                                      head_dim=hd)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("bias", [False, True])
+def test_restore_kv_grouped_sharded_launch(bias):
+    """tp=4 (conftest's forced host devices): the kernel runs under
+    shard_map, one launch per device on its own KV heads, and matches the
+    oracle with the outputs left KV-sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("model",))
+    kv3 = NamedSharding(mesh, P(None, None, "model"))
+    G, S, D, Kv, hd = 2, 64, 128, 4, 128
+    KV = Kv * hd
+    h = jnp.asarray(RNG.normal(size=(G, S, D)), jnp.float32)
+    wk = jax.device_put(jnp.asarray(RNG.normal(size=(G, D, KV)) * D ** -0.5,
+                                    jnp.float32), kv3)
+    wv = jax.device_put(jnp.asarray(RNG.normal(size=(G, D, KV)) * D ** -0.5,
+                                    jnp.float32), kv3)
+    bk = bv = None
+    if bias:
+        b2 = NamedSharding(mesh, P(None, "model"))
+        bk = jax.device_put(jnp.asarray(RNG.normal(size=(G, KV)),
+                                        jnp.float32), b2)
+        bv = jax.device_put(jnp.asarray(RNG.normal(size=(G, KV)),
+                                        jnp.float32), b2)
+    ang = (jnp.arange(S, dtype=jnp.float32)[:, None]
+           * 1e4 ** (-jnp.arange(hd // 2) / (hd // 2)))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    got = ops.restore_kv_grouped(h, wk, wv, bk, bv, cos, sin, head_dim=hd,
+                                 use_pallas=True, kv_sharding=kv3)
+    want = ref.restore_kv_grouped_ref(h, wk, wv, bk, bv, cos, sin,
+                                      head_dim=hd)
+    for g, w in zip(got, want):
+        assert g.sharding.spec == P(None, None, "model")
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   **_tol(jnp.float32))
 
 
 @pytest.mark.parametrize("Sq,Skv,hd,group", [(64, 64, 16, 1), (64, 64, 32, 2),

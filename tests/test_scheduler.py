@@ -3,10 +3,7 @@ import dataclasses
 import math
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis - seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.config.hardware import GB, PAPER_A100, HardwareProfile
 from repro.configs import get_arch

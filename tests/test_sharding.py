@@ -1,10 +1,7 @@
 """Sharding rules: head padding invariants (hypothesis), spec dedup,
 vocab padding."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis - seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import default_rules, pad_heads

@@ -1,10 +1,7 @@
 """Chunk store + two-stage saver: roundtrips, striping, resume, hypothesis."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis - seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.storage import (ChunkStore, DirectSaver, SimulatedSSD,
                            SnapshotTask, TwoStageSaver, make_array)

@@ -72,7 +72,6 @@ def test_tp_context_identity_when_single_device():
     x = jnp.arange(8.0).reshape(2, 4)
     assert one.shard_kv(x, 1) is x                 # placement is identity
     assert one.replicate(x) is x
-    assert one.unshard(x) is x
     assert one.kv_sharding(2, 1) is None
     one.validate_heads(3)                          # never raises when off
 
@@ -92,8 +91,18 @@ def test_tp_context_spmd_shardings():
                for s in sharded.addressable_shards)
     rep = tp.replicate(jnp.arange(4))
     assert len(rep.sharding.device_set) == 4
-    back = tp.unshard(sharded)
-    assert len(back.sharding.device_set) == 1
+    # the seam back into unsharded code replicates the head shards
+    back = tp.replicate(sharded)
+    assert back.sharding.is_fully_replicated
+    assert all(s.data.shape == pool.shape for s in back.addressable_shards)
+
+
+def test_tp_context_raises_when_devices_are_missing():
+    """A tp wider than the visible devices is an error, never a silent
+    single-device run (conftest forces 4 host devices, so 8 is short)."""
+    assert len(jax.devices()) < 8
+    with pytest.raises(ValueError, match="tp=8"):
+        tp_lib.TPContext(8)
 
 
 def test_seams_are_identity_without_active_context():
