@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -46,6 +46,13 @@ class RestoreResult:
     timeline: Timeline               # simulated restoration timing
     wall_time: float                 # actual CPU seconds (functional path)
     n_tokens: int
+
+
+class Saved(NamedTuple):
+    """What one save made restorable: the tokens the manifest grew by,
+    and the bytes copied off the device to persist them."""
+    tokens: int
+    d2h_bytes: int
 
 
 class HCacheManager:
@@ -298,7 +305,7 @@ class HCacheManager:
 
     # ----------------------------------------------------------------- save
     def save_prefill(self, session: str, tokens: np.ndarray, prefill_out:
-                     dict, *, start: int = 0) -> None:
+                     dict, *, start: int = 0) -> Saved:
         """Persist one sequence's prefill state (B must be 1 in `out`).
         The mapping between prefill outputs and persisted pieces
         (hidden/KV row naming) is the FamilyAdapter's."""
@@ -319,19 +326,22 @@ class HCacheManager:
         self.store.put_blob(session, "tok", 0, toks if start == 0 else
                             np.concatenate([self._tokens(session), toks]))
         kinds = self.cfg.block_kinds()
+        d2h = 0                   # adapter reads copy device arrays out
         for li, method in enumerate(methods):
             if kinds[li] != BlockKind.ATTENTION:
                 continue  # SSM layers handled via state blobs below
             if method == "hidden":
-                self._append_hidden(session, li, start,
-                                    adapter.prefill_hidden(prefill_out, li))
+                h = adapter.prefill_hidden(prefill_out, li)
+                d2h += h.nbytes
+                self._append_hidden(session, li, start, h)
             elif method == "kv":
                 k, v = adapter.prefill_kv(prefill_out, li)
+                d2h += k.nbytes + v.nbytes
                 self.store.append_tokens(session, "kvk", li, start,
                                          k.reshape(k.shape[0], -1))
                 self.store.append_tokens(session, "kvv", li, start,
                                          v.reshape(v.shape[0], -1))
-        self._save_ssm_states(session, prefill_out)
+        d2h += self._save_ssm_states(session, prefill_out)
         manifest = {
             "n_tokens": int(start + tokens.shape[-1]),
             "methods": methods,
@@ -339,8 +349,9 @@ class HCacheManager:
         }
         if adapter.has_cross:
             if "enc_out" in prefill_out:
-                self.store.put_blob(session, "enc", 0,
-                                    np.asarray(prefill_out["enc_out"][0]))
+                enc = np.asarray(prefill_out["enc_out"][0])
+                d2h += enc.nbytes
+                self.store.put_blob(session, "enc", 0, enc)
                 manifest["enc_len"] = int(prefill_out["enc_out"].shape[1])
             elif prev:
                 # resume prefill (no encoder pass): keep the stored
@@ -348,9 +359,11 @@ class HCacheManager:
                 manifest["enc_len"] = int(prev.get("enc_len", 0))
         self.store.flush(session)
         self.store.put_manifest(session, manifest)
+        return Saved(int(tokens.shape[-1]), d2h)
 
     def save_session_pause(self, session: str, cache: dict,
-                           n_tokens: int, *, tokens_tail: np.ndarray) -> None:
+                           n_tokens: int, *, tokens_tail: np.ndarray)\
+            -> Saved:
         """On eviction after decoding: dump kv-layer tails + SSM states from
         the live cache (they are accelerator-resident; decode only streamed
         the hidden states). Keeps the store restorable at ``n_tokens``."""
@@ -368,6 +381,7 @@ class HCacheManager:
         kinds = self.cfg.block_kinds()
         adapter = self.model.adapter
         k_name, v_name = adapter.kv_names or ("k", "v")
+        d2h = 0
         for li, method in enumerate(methods):
             if (kinds[li] != BlockKind.ATTENTION or method != "kv"
                     or n_tokens <= prev_n):
@@ -375,18 +389,17 @@ class HCacheManager:
             idx = adapter.kv_row(li)
             k = np.asarray(cache[k_name][idx][0][prev_n:n_tokens])
             v = np.asarray(cache[v_name][idx][0][prev_n:n_tokens])
+            d2h += k.nbytes + v.nbytes
             self.store.append_tokens(session, "kvk", li, prev_n,
                                      k.reshape(k.shape[0], -1))
             self.store.append_tokens(session, "kvv", li, prev_n,
                                      v.reshape(v.shape[0], -1))
         if "ssm" in cache:
-            self.store.put_blob(session, "state_conv", 0,
-                                np.asarray(cache["conv"]))
-            self.store.put_blob(session, "state_ssm", 0,
-                                np.asarray(cache["ssm"]))
+            d2h += self._put_states(session, cache["conv"], cache["ssm"])
         self.store.flush(session)
         manifest["n_tokens"] = int(n_tokens)
         self.store.put_manifest(session, manifest)
+        return Saved(max(int(n_tokens) - prev_n, 0), d2h)
 
     def _append_hidden(self, session: str, layer: int, start: int,
                        h: np.ndarray) -> None:
@@ -398,27 +411,33 @@ class HCacheManager:
             self.store.append_tokens(session, "h", layer, start,
                                      h.astype(self.store_dtype))
 
-    def _save_ssm_states(self, session: str, out: dict) -> None:
+    def _save_ssm_states(self, session: str, out: dict) -> int:
         states = out.get("states") or out.get("mamba_states")
         if states is None:
-            return
-        conv, ssm = states
-        self.store.put_blob(session, "state_conv", 0, np.asarray(conv))
-        self.store.put_blob(session, "state_ssm", 0, np.asarray(ssm))
+            return 0
+        return self._put_states(session, *states)
+
+    def _put_states(self, session: str, conv, ssm) -> int:
+        """Store SSM conv/ssm state blobs; returns the bytes copied."""
+        conv, ssm = np.asarray(conv), np.asarray(ssm)
+        self.store.put_blob(session, "state_conv", 0, conv)
+        self.store.put_blob(session, "state_ssm", 0, ssm)
+        return conv.nbytes + ssm.nbytes
 
     def save_decode_hidden(self, session_ids: Sequence[Optional[str]],
                            hidden, lengths: np.ndarray) -> float:
         """Two-stage save of one decode step's hidden states.
 
         hidden: (L, B, 1, D); lengths: (B,) position of the new token.
-        Returns the stage-1 (snapshot) virtual cost in seconds.
+        Returns the stage-1 (snapshot) virtual cost in seconds (the
+        engine counts the device->host bytes itself).
 
         The whole step is ONE layer-stacked (L, B', 1, D) snapshot for
         the plain-codec rows (the device buffer is already layer-major —
         stage 1 is a single contiguous copy, not L ring submissions);
         the stage-2 daemon splits per (layer, sequence). The snapshot
-        byte count — and so ``snapshot_cost`` accounting — is unchanged
-        from the per-layer form."""
+        byte count — and so the returned cost — is unchanged from the
+        per-layer form."""
         h = np.asarray(hidden)
         L = h.shape[0]
         all_layers = list(range(L))

@@ -54,6 +54,8 @@ from repro.config.arch import BlockKind
 from repro.core.cost_model import (MethodTimes, layer_costs,
                                    link_priced_times, method_times)
 from repro.core.scheduler import Schedule
+from repro.core.trace import (RESTORE_PROJECT, RESTORE_READ, RESTORE_UPLOAD,
+                              RESTORE_WRITE, span)
 from repro.kernels import ops
 from repro.models.layers.norm import apply_norm
 from repro.models.layers.rope import rope_angles
@@ -763,7 +765,11 @@ class RestorationExecutor:
     batched upload + one stacked projection + one grouped sink write per
     group. ``dispatch_count`` tallies the device dispatches the restore
     issued; ``project_wall`` the wall seconds inside projection calls —
-    both surfaced by bench_restore_batch."""
+    both surfaced by bench_restore_batch. ``read_wall``, ``upload_wall``
+    and ``write_wall`` are the seconds of the executor's store-read,
+    host->device and cache-write spans (core/trace.py), ``h2d_bytes``
+    the bytes it handed to the device; the engine folds them into its
+    metrics when the restore completes."""
 
     def __init__(self, mgr, params, session: str,
                  sink: Optional[RestoreSink] = None, start_token: int = 0):
@@ -884,14 +890,12 @@ class RestorationExecutor:
         self._re_windows = None
         self._re_next = 0
         self._finished = False
-        # striped-device completion, relative to the device clocks at
-        # executor start (the clocks are shared and monotonic across
-        # restores; under concurrent restores this correctly includes
-        # queueing behind the other session's reads)
-        self._io_base = mgr.store.read_completion()
-        self.io_measured = 0.0
         self.wall_time = 0.0
         self.project_wall = 0.0
+        self.read_wall = 0.0
+        self.upload_wall = 0.0
+        self.write_wall = 0.0
+        self.h2d_bytes = 0
         self.dispatch_count = 0
         self._enc_out: Optional[np.ndarray] = None
         # online profiling (DESIGN.md §13): per-task observed durations.
@@ -934,15 +938,24 @@ class RestorationExecutor:
 
     def attach_sink(self, sink: RestoreSink) -> None:
         self.sink = sink
-        for op, args in self._pending:
-            getattr(sink, op)(*args)
+        with span(RESTORE_WRITE, self, "write_wall"):
+            for op, args in self._pending:
+                getattr(sink, op)(*args)
         self._pending.clear()
 
     def _emit(self, op: str, *args) -> None:
         if self.sink is not None:
-            getattr(self.sink, op)(*args)
+            with span(RESTORE_WRITE, self, "write_wall"):
+                getattr(self.sink, op)(*args)
         else:
             self._pending.append((op, args))
+
+    def _upload(self, host: np.ndarray):
+        """One host array handed to the device."""
+        with span(RESTORE_UPLOAD, self, "upload_wall"):
+            out = jnp.asarray(host)
+        self.h2d_bytes += host.nbytes
+        return out
 
     def timeline(self):
         """Timeline derived from the order tasks actually executed in."""
@@ -1001,7 +1014,8 @@ class RestorationExecutor:
         # dispatched, block-drain the stragglers so done means done
         self._reap_kv(block=all(self._done))
         if self.done and not self._finished and self.sink is not None:
-            self.sink.finish(self.n_tokens)
+            with span(RESTORE_WRITE, self, "write_wall"):
+                self.sink.finish(self.n_tokens)
             self._finished = True
         self.wall_time += time.perf_counter() - t0
         return self.done
@@ -1109,11 +1123,6 @@ class RestorationExecutor:
     def _is_attn(self, layer: int) -> bool:
         return layer in self._row_of
 
-    def _measure(self, *completions: float) -> None:
-        done = max(completions, default=0.0)
-        if done:
-            self.io_measured = max(self.io_measured, done - self._io_base)
-
     def _observe_read(self, idx: int, kind: str, tickets,
                       work: float, bucket: int) -> None:
         """Fold a reaped async read into the profiler. The sync path
@@ -1142,16 +1151,15 @@ class RestorationExecutor:
         if got is None:
             return self._hbuf.pop(layer)
         idx, lr, ls = got
-        ar = lr.wait()
-        tickets = list(lr.tickets)
-        if ls is not None:
-            sr = ls.wait()
-            tickets += list(ls.tickets)
-            self._measure(ar.completion, sr.completion)
-            data = dequantize_hidden_int8(ar.data, sr.data)
-        else:
-            self._measure(ar.completion)
-            data = ar.data
+        with span(RESTORE_READ, self, "read_wall"):
+            ar = lr.wait()
+            tickets = list(lr.tickets)
+            if ls is not None:
+                sr = ls.wait()
+                tickets += list(ls.tickets)
+                data = dequantize_hidden_int8(ar.data, sr.data)
+            else:
+                data = ar.data
         self._observe_read(idx, "io_h", tickets,
                            self._task_work(self.tasks[idx]), self._bucket)
         return data
@@ -1161,31 +1169,40 @@ class RestorationExecutor:
         ``block=True`` drains every outstanding ticket (end of graph)."""
         if not self._kvio:
             return
-        cfg, dtype = self.mgr.cfg, self.model.dtype
         remaining = []
         for entry in self._kvio:
             idx, layer, rk, rv = entry
             if not block and not (rk.ready() and rv.ready()):
                 remaining.append(entry)
                 continue
-            ak, av = rk.wait(), rv.wait()
-            self._measure(ak.completion, av.completion)
+            with span(RESTORE_READ, self, "read_wall"):
+                ak, av = rk.wait(), rv.wait()
             self._observe_read(idx, "io_kv",
                                list(rk.tickets) + list(rv.tickets),
                                self._task_work(self.tasks[idx]),
                                self._bucket)
-            hd = cfg.head_dim_
-            ne = self.n_eff
-            k = jnp.asarray(ak.data).reshape(1, ne, cfg.n_kv_heads, hd)
-            v = jnp.asarray(av.data).reshape(1, ne, cfg.n_kv_heads, hd)
-            self.dispatch_count += 3           # 2 uploads + 1 sink write
-            self._emit("put_kv", self._row_of[layer], k.astype(dtype),
-                       v.astype(dtype), self.start_token)
+            self._put_kv(layer, ak.data, av.data)
         self._kvio = remaining
+
+    def _put_kv(self, layer: int, k_rows: np.ndarray,
+                v_rows: np.ndarray) -> None:
+        """Upload one fetched kv layer's rows and hand them to the sink."""
+        cfg, dtype = self.mgr.cfg, self.model.dtype
+        shape = (1, self.n_eff, cfg.n_kv_heads, cfg.head_dim_)
+        with span(RESTORE_UPLOAD, self, "upload_wall"):
+            k = jnp.asarray(k_rows).reshape(shape).astype(dtype)
+            v = jnp.asarray(v_rows).reshape(shape).astype(dtype)
+        self.h2d_bytes += k_rows.nbytes + v_rows.nbytes
+        self.dispatch_count += 3           # 2 uploads + 1 sink write
+        self._emit("put_kv", self._row_of[layer], k, v, self.start_token)
 
     def _exec_io_h(self, t: Task) -> None:
         if not self._is_attn(t.layer):
             return          # mamba layers restore via the state blob
+        with span(RESTORE_READ, self, "read_wall"):
+            self._read_h(t)
+
+    def _read_h(self, t: Task) -> None:
         store, sess, n = self.mgr.store, self.session, self.n_tokens
         d = self.start_token
         submit = getattr(store, "submit_layer_read", None)
@@ -1195,12 +1212,10 @@ class RestorationExecutor:
                                            start_token=d)
                 s = store.read_layer_async(sess, "hs", t.layer, n,
                                            start_token=d)
-                self._measure(q.completion, s.completion)
                 self._hbuf[t.layer] = dequantize_hidden_int8(q.data, s.data)
             else:
                 r = store.read_layer_async(sess, "h", t.layer, n,
                                            start_token=d)
-                self._measure(r.completion)
                 self._hbuf[t.layer] = r.data
             return
         # submit leg: tickets staged until the projection consumes them
@@ -1218,23 +1233,16 @@ class RestorationExecutor:
         d = self.start_token
         submit = getattr(store, "submit_layer_read", None)
         if submit is None:
-            cfg = self.mgr.cfg
-            rk = store.read_layer_async(sess, "kvk", t.layer, n,
-                                        start_token=d)
-            rv = store.read_layer_async(sess, "kvv", t.layer, n,
-                                        start_token=d)
-            self._measure(rk.completion, rv.completion)
-            hd = cfg.head_dim_
-            ne = self.n_eff
-            k = jnp.asarray(rk.data).reshape(1, ne, cfg.n_kv_heads, hd)
-            v = jnp.asarray(rv.data).reshape(1, ne, cfg.n_kv_heads, hd)
-            self.dispatch_count += 3           # 2 uploads + 1 sink write
-            self._emit("put_kv", self._row_of[t.layer],
-                       k.astype(self.model.dtype),
-                       v.astype(self.model.dtype), d)
+            with span(RESTORE_READ, self, "read_wall"):
+                rk = store.read_layer_async(sess, "kvk", t.layer, n,
+                                            start_token=d)
+                rv = store.read_layer_async(sess, "kvv", t.layer, n,
+                                            start_token=d)
+            self._put_kv(t.layer, rk.data, rv.data)
             return
-        rk = submit(sess, "kvk", t.layer, n, start_token=d)
-        rv = submit(sess, "kvv", t.layer, n, start_token=d)
+        with span(RESTORE_READ, self, "read_wall"):
+            rk = submit(sess, "kvk", t.layer, n, start_token=d)
+            rv = submit(sess, "kvv", t.layer, n, start_token=d)
         self._kvio.append((self._cur_idx, t.layer, rk, rv))
 
     def _exec_project(self, t: Task) -> None:
@@ -1249,29 +1257,31 @@ class RestorationExecutor:
         # what lets reads of later layers stream on the shard workers
         # while this projection computes
         fetched = {li: self._collect_hidden(li) for li in members}
-        h0 = fetched[members[0]]
-        stack = np.zeros((G, S, h0.shape[-1]), h0.dtype)
+        with span(RESTORE_READ, self, "read_wall"):
+            h0 = fetched[members[0]]
+            stack = np.zeros((G, S, h0.shape[-1]), h0.dtype)
+            for g, li in enumerate(members):
+                stack[g, :n] = fetched.pop(li)
         rows = [self._row_of[li] for li in members]
-        for g, li in enumerate(members):
-            stack[g, :n] = fetched.pop(li)
         # pad to the stable group width with a repeated row id over zero
         # hidden states; padded outputs are sliced away below
         rows_pad = np.asarray(rows + [rows[-1]] * (G - len(rows)), np.int32)
         # RoPE at absolute positions: a suffix restore rotates with the
         # tables sliced at its divergence offset
         cos, sin = pack.rope_tables(S, self.start_token)
-        t0 = time.perf_counter()
         # ONE host->device upload (replicated across the mesh under tp)
-        hidden = pack.place_hidden(stack)
-        k, v = _project_group_jit(
-            hidden, jnp.asarray(rows_pad), pack.ln_scale, pack.ln_bias,
-            pack.wk, pack.wv, pack.bk, pack.bv, cos, sin,
-            norm_kind=pack.norm_kind, eps=pack.norm_eps,
-            head_dim=pack.head_dim, use_rope=pack.use_rope,
-            dtype=pack.dtype, use_pallas=ops.on_tpu(), interpret=None,
-            kv_sharding=pack.out_sharding)
-        jax.block_until_ready((k, v))
-        self.project_wall += time.perf_counter() - t0
+        with span(RESTORE_UPLOAD, self, "upload_wall"):
+            hidden = pack.place_hidden(stack)
+        self.h2d_bytes += stack.nbytes
+        with span(RESTORE_PROJECT, self, "project_wall"):
+            k, v = _project_group_jit(
+                hidden, jnp.asarray(rows_pad), pack.ln_scale, pack.ln_bias,
+                pack.wk, pack.wv, pack.bk, pack.bv, cos, sin,
+                norm_kind=pack.norm_kind, eps=pack.norm_eps,
+                head_dim=pack.head_dim, use_rope=pack.use_rope,
+                dtype=pack.dtype, use_pallas=ops.on_tpu(), interpret=None,
+                kv_sharding=pack.out_sharding)
+            jax.block_until_ready((k, v))
         g_real = len(members)
         self.dispatch_count += 3     # upload + projection + grouped write
         self._emit("put_kv_group", tuple(rows),
@@ -1305,34 +1315,38 @@ class RestorationExecutor:
 
     def _exec_blob(self, t: Task) -> None:
         store, sess = self.mgr.store, self.session
-        conv = jnp.asarray(store.get_blob(sess, "state_conv", 0))
-        ssm = jnp.asarray(store.get_blob(sess, "state_ssm", 0))
-        self._emit("put_states", conv, ssm)
+        with span(RESTORE_READ, self, "read_wall"):
+            conv = np.asarray(store.get_blob(sess, "state_conv", 0))
+            ssm = np.asarray(store.get_blob(sess, "state_ssm", 0))
+        self._emit("put_states", self._upload(conv), self._upload(ssm))
 
     def _exec_io_enc(self, t: Task) -> None:
         # the encoder blob lives whole on its owning shard; the submit
         # path overlaps the read with decoder-side restoration and the
         # cross-projection reaps it. Charged only on the virtual clock
-        # (CrossTimes.io) and excluded from io_measured.
+        # (CrossTimes.io).
         submit = getattr(self.mgr.store, "submit_blob_read", None)
-        if submit is None:
-            self._enc_out = np.asarray(
-                self.mgr.store.get_blob(self.session, "enc", 0))
-            return
-        self._encio = (self._cur_idx, submit(self.session, "enc", 0))
+        with span(RESTORE_READ, self, "read_wall"):
+            if submit is None:
+                self._enc_out = np.asarray(
+                    self.mgr.store.get_blob(self.session, "enc", 0))
+                return
+            self._encio = (self._cur_idx, submit(self.session, "enc", 0))
 
     def _exec_project_cross(self, t: Task) -> None:
         from repro.models import encdec as encdec_mod
         if self._encio is not None:
             idx, ticket = self._encio
             self._encio = None
-            parts = ticket.wait()
-            self._enc_out = np.asarray(parts[0])
+            with span(RESTORE_READ, self, "read_wall"):
+                parts = ticket.wait()
+                self._enc_out = np.asarray(parts[0])
             self._observe_read(idx, "io_enc", [ticket],
                                self._task_work(self.tasks[idx]),
                                self._enc_bucket)
-        enc_out = jnp.asarray(self._enc_out)[None]
+        enc_out = self._upload(self._enc_out)[None]
         self._enc_out = None
-        ck, cv = encdec_mod.cross_kv(self.params, enc_out, self.model.h)
+        with span(RESTORE_PROJECT):
+            ck, cv = encdec_mod.cross_kv(self.params, enc_out, self.model.h)
         self.dispatch_count += 2             # upload+projection, sink write
         self._emit("put_cross", ck, cv, enc_out.shape[1])

@@ -32,6 +32,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from repro.core.trace import PUMP_CALL, PUMP_WAIT, span
 from repro.serving.request import Request
 
 
@@ -226,7 +227,8 @@ class EnginePump:
                         was_busy = False
                         drain = True
                     else:
-                        self._cond.wait(timeout=self.idle_wait)
+                        with span(PUMP_WAIT):
+                            self._cond.wait(timeout=self.idle_wait)
                 if self._stop and (self._force_stop or not self._work()):
                     break
                 while self._inbox:
@@ -236,10 +238,11 @@ class EnginePump:
                 eng.mgr.saver.drain()
             for fut, fn, args, kw in calls:
                 if fut.set_running_or_notify_cancel():
-                    try:
-                        fut.set_result(fn(*args, **kw))
-                    except BaseException as e:   # noqa: BLE001 - relayed
-                        fut.set_exception(e)
+                    with span(PUMP_CALL):
+                        try:
+                            fut.set_result(fn(*args, **kw))
+                        except BaseException as e:  # noqa: BLE001 - relayed
+                            fut.set_exception(e)
             if self._engine_busy():
                 eng.step()
                 was_busy = True

@@ -46,6 +46,8 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.trace import PREFILL_GATHER, span
+
 
 class FamilyAdapter:
     kind: str = "?"
@@ -165,7 +167,10 @@ class LMAdapter(FamilyAdapter):
                                  positions=positions)
 
     def prefill_chunk(self, params, seq, chunk, hist, *, capture_hidden):
-        hist_kv = seq.view.gather_hist(hist) if hist else None
+        hist_kv = None
+        if hist:
+            with span(PREFILL_GATHER):
+                hist_kv = seq.view.gather_hist(hist)
         batch = {"tokens": jnp.asarray(chunk, jnp.int32)[None]}
         return self.prefill(params, batch, capture_hidden=capture_hidden,
                             hist_kv=hist_kv,
@@ -348,8 +353,9 @@ class EncDecAdapter(FamilyAdapter):
         if hist:
             # resume / round-N prefill: no encoder pass — self-attention
             # history and the cross state come from the slot's view
-            hk, hv = seq.view.gather_hist(hist)
-            ck, cv, _ = seq.view.cross_state()
+            with span(PREFILL_GATHER):
+                hk, hv = seq.view.gather_hist(hist)
+                ck, cv, _ = seq.view.cross_state()
             return encdec.decode_prefill(
                 params, toks, None, self.model.h,
                 capture_hidden=capture_hidden, emit_kv=True,

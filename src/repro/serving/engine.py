@@ -52,9 +52,10 @@ Crash recovery: a fresh engine over the same ChunkStore can resume any
 session (`recoverable_sessions`) — serving-side fault tolerance is HCache
 itself.
 
-Metrics per request: wall TTFT, simulated restoration time (hardware
-profile, restored sessions only), TBT; engine-level counters plus
-occupancy/fragmentation gauges for the benchmark harness.
+Metrics per request: wall TTFT, TBT; engine-level counters plus
+occupancy/fragmentation gauges for the benchmark harness. Host spans
+(core/trace.py) name the work inside a step in a profiler trace and
+feed the wall-time counters.
 """
 from __future__ import annotations
 
@@ -70,6 +71,10 @@ import numpy as np
 from repro.core.capacity import (CapacityManager, EvictionPolicy,
                                  AdmissionPolicy, FIFOAdmission, LRUEviction)
 from repro.core.hcache import HCacheManager
+from repro.core.trace import (ADMIT, DECODE_FORWARD, DECODE_SAMPLE,
+                              DECODE_SAVE, PREFILL_ABSORB, PREFILL_FORWARD,
+                              PREFILL_SAMPLE, PREFILL_SAVE, RETIRE_SAVE,
+                              span)
 from repro.distributed import tp as tp_lib
 from repro.models.model import Model
 from repro.serving.kv_cache import (KVCacheBackend, PagedBackend, ViewSink,
@@ -83,10 +88,7 @@ from repro.serving.sampling import sample
 class EngineMetrics:
     ttft_wall: List[float] = dataclasses.field(default_factory=list)
     # two TTFT populations: sessions that went through restoration vs
-    # cold starts. ``ttft_sim`` holds simulated restoration makespans for
-    # restored sessions ONLY (a cold start has no restoration to
-    # simulate; recording 0.0 for it would pollute the mean).
-    ttft_sim: List[float] = dataclasses.field(default_factory=list)
+    # cold starts
     ttft_wall_restored: List[float] = dataclasses.field(default_factory=list)
     ttft_wall_cold: List[float] = dataclasses.field(default_factory=list)
     tbt_wall: List[float] = dataclasses.field(default_factory=list)
@@ -97,10 +99,7 @@ class EngineMetrics:
     restore_sim_resume: List[float] = dataclasses.field(default_factory=list)
     preemptions: int = 0                # mid-stream evictions (PAUSED)
     restored_tokens: int = 0
-    restore_steps: int = 0              # engine steps that ran restore tasks
-    restore_io_measured: float = 0.0    # striped-device completion (sim SSD)
     decode_steps: int = 0
-    snapshot_cost: float = 0.0
     # occupancy / fragmentation gauges (KVCacheBackend.occupancy, sampled
     # once per engine step while any slot is occupied). live = tokens in
     # occupied slots; reserved = capacity handed out to them — the gap is
@@ -151,6 +150,20 @@ class EngineMetrics:
     device_gauges: List[dict] = dataclasses.field(default_factory=list)
     restore_project_wall: float = 0.0   # sum over completed restores
     restore_wall_sum: float = 0.0
+    # host wall time and traffic measured where the work happens (spans of
+    # core/trace.py), as running sums: a reader takes differences over
+    # its window. Restore figures are folded in when a restore completes.
+    queue_wait_sum: float = 0.0         # s from arrival (or pause) to a slot
+    admitted: int = 0                   # successful placements
+    prefill_wall_sum: float = 0.0       # s in _prefill_step, all chunks
+    prefill_tokens: int = 0             # prompt tokens those chunks ran
+    save_wall_sum: float = 0.0          # s in the prefill/decode/retire saves
+    saved_tokens: int = 0               # tokens the saves made restorable
+    save_d2h_bytes: int = 0             # bytes they copied off the device
+    restore_read_wall: float = 0.0      # s in store reads and assembly
+    restore_upload_wall: float = 0.0    # s handing host arrays to the device
+    restore_write_wall: float = 0.0     # s writing restored pieces to cache
+    restore_h2d_bytes: int = 0          # bytes restores handed to the device
 
     @property
     def restore_bubble_mean(self) -> float:
@@ -303,6 +316,7 @@ class InferenceEngine:
         if request.arrival_step < 0:
             seq.request.arrival_step = self.step_count
         seq.enqueue_step = self.step_count
+        seq.enqueue_time = seq.request.arrival_time
         self.queue.append(seq)
         return seq
 
@@ -381,22 +395,24 @@ class InferenceEngine:
             max(need - self._shared_prefix_estimate(seq), 1))
 
     def _admit(self) -> None:
-        while self.queue:
-            slot = self._free_slot()
-            if slot is None:
-                break
-            seq = self.admission.select(tuple(self.queue), self)
-            if seq is None:
-                break
-            if not self._can_reserve_for(seq):
-                # allocator backpressure: a free slot exists but the page
-                # pool cannot hold the session — wait for retires/frees
-                self.metrics.alloc_stalls += 1
-                break
-            self.queue.remove(seq)
-            if not self._place(seq, slot):
-                break
-        self._prefetch_queued()
+        with span(ADMIT):
+            while self.queue:
+                slot = self._free_slot()
+                if slot is None:
+                    break
+                seq = self.admission.select(tuple(self.queue), self)
+                if seq is None:
+                    break
+                if not self._can_reserve_for(seq):
+                    # allocator backpressure: a free slot exists but the
+                    # page pool cannot hold the session — wait for
+                    # retires/frees
+                    self.metrics.alloc_stalls += 1
+                    break
+                self.queue.remove(seq)
+                if not self._place(seq, slot):
+                    break
+            self._prefetch_queued()
 
     def _adopt_shared_prefix(self, seq: SequenceState, slot: int) -> int:
         """Map the longest shared prefix of this session into the free
@@ -491,6 +507,8 @@ class InferenceEngine:
             return False
         seq.slot = slot
         seq.admit_step = self.step_count
+        self.metrics.queue_wait_sum += time.perf_counter() - seq.enqueue_time
+        self.metrics.admitted += 1
         seq.view = self.kv.view(slot)
         self.slots[slot] = seq
         self.sessions[sid] = seq
@@ -578,12 +596,13 @@ class InferenceEngine:
         if victim is None:
             return
         slot = victim.slot
-        self._pause_slot(slot)
-        waiting = [s for s in self.queue if s is not victim]
-        seq = self.admission.select(tuple(waiting), self)
-        if seq is not None:
-            self.queue.remove(seq)
-            self._place(seq, slot)
+        with span(ADMIT):
+            self._pause_slot(slot)
+            waiting = [s for s in self.queue if s is not victim]
+            seq = self.admission.select(tuple(waiting), self)
+            if seq is not None:
+                self.queue.remove(seq)
+                self._place(seq, slot)
 
     def _pause_slot(self, i: int) -> None:
         """Evict the resident of slot ``i`` mid-decode: dump restorable
@@ -591,15 +610,8 @@ class InferenceEngine:
         sequence as PAUSED. The last sampled token (whose KV does not
         exist yet) becomes the 1-token resume prefill after restoration."""
         s = self.slots[i]
-        sid = s.request.session_id
-        n = s.total_len
-        self.mgr.saver.drain()
-        self.mgr.save_session_pause(
-            sid, s.view.snapshot(), n - 1,
-            tokens_tail=np.asarray(s.generated[s.tok_saved:-1], np.int32))
-        self._after_save(sid)
+        self._checkpoint(s)
         self._publish_slot(s)
-        s.tok_saved = len(s.generated) - 1
         s.gen_absorbed = len(s.generated)
         s.pending_prompt = np.asarray([s.generated[-1]], np.int32)
         s.pending_from_gen = True
@@ -612,6 +624,7 @@ class InferenceEngine:
         s.view = None
         s.pauses += 1
         s.enqueue_step = self.step_count
+        s.enqueue_time = time.perf_counter()
         self.slots[i] = None
         self.queue.append(s)
         self.metrics.preemptions += 1
@@ -702,14 +715,7 @@ class InferenceEngine:
                 raise ValueError(
                     "forking a resident session requires save_hidden "
                     "(its history lives only in streams it never saved)")
-            n = seq.total_len
-            self.mgr.saver.drain()
-            self.mgr.save_session_pause(
-                src, seq.view.snapshot(), n - 1,
-                tokens_tail=np.asarray(seq.generated[seq.tok_saved:-1],
-                                       np.int32))
-            self._after_save(src)
-            seq.tok_saved = len(seq.generated) - 1
+            self._checkpoint(seq)
         man = self.mgr.fork_session(src, new_id,
                                     share=self.prefix_sharing)
         if (self.prefix_index is not None and seq is not None
@@ -783,11 +789,10 @@ class InferenceEngine:
         """Advance every RESTORING session by a bounded number of pipeline
         tasks. Several sessions restore concurrently; the decode batch of
         active sessions runs in the same engine step regardless."""
-        ran = False
+        m = self.metrics
         for seq in self.slots:
             if seq is None or seq.phase != Phase.RESTORING:
                 continue
-            ran = True
             if seq.executor.step(self.restore_tasks_per_step):
                 ex = seq.executor
                 seq.executor = None
@@ -795,20 +800,18 @@ class InferenceEngine:
                 seq.history_len = ex.n_tokens
                 seq.restore_sim = ex.timeline().makespan
                 seq.restore_wall = ex.wall_time
-                self.metrics.restored_tokens += (
-                    ex.n_tokens - getattr(ex, "start_token", 0))
-                self.metrics.restore_sim_all.append(seq.restore_sim)
+                m.restored_tokens += ex.n_tokens - ex.start_token
+                m.restore_sim_all.append(seq.restore_sim)
                 if seq.pending_from_gen:       # resume of a paused session
-                    self.metrics.restore_sim_resume.append(seq.restore_sim)
-                self.metrics.restore_io_measured = max(
-                    self.metrics.restore_io_measured, ex.io_measured)
-                self.metrics.restore_project_wall += getattr(
-                    ex, "project_wall", 0.0)
-                self.metrics.restore_wall_sum += ex.wall_time
+                    m.restore_sim_resume.append(seq.restore_sim)
+                m.restore_project_wall += ex.project_wall
+                m.restore_wall_sum += ex.wall_time
+                m.restore_read_wall += ex.read_wall
+                m.restore_upload_wall += ex.upload_wall
+                m.restore_write_wall += ex.write_wall
+                m.restore_h2d_bytes += ex.h2d_bytes
                 self._record_calibration(ex)
                 seq.phase = Phase.PREFILL
-        if ran:
-            self.metrics.restore_steps += 1
 
     def _record_calibration(self, ex) -> None:
         """Scheduler-calibration gauges from one finished restore:
@@ -851,15 +854,22 @@ class InferenceEngine:
         if len(remaining) == 0:
             seq.phase = Phase.DECODE
             return
+        t0 = time.perf_counter()
+        m = self.metrics
         chunk = remaining[:self.prefill_chunk] if ad.chunkable else remaining
         hist = seq.history_len + seq.prefill_done
-        out = ad.prefill_chunk(self.params, seq, chunk, hist,
-                               capture_hidden=self.save_hidden)
-        ad.absorb_prefill(seq.view, out, len(chunk), hist)
-        seq.view.set_length(hist + len(chunk))
+        with span(PREFILL_FORWARD):
+            out = ad.prefill_chunk(self.params, seq, chunk, hist,
+                                   capture_hidden=self.save_hidden)
+        with span(PREFILL_ABSORB):
+            ad.absorb_prefill(seq.view, out, len(chunk), hist)
+            seq.view.set_length(hist + len(chunk))
         if self.save_hidden:
             sid = seq.request.session_id
-            self.mgr.save_prefill(sid, np.asarray(chunk), out, start=hist)
+            with span(PREFILL_SAVE, m, "save_wall_sum"):
+                saved = self.mgr.save_prefill(sid, np.asarray(chunk), out,
+                                              start=hist)
+            self._count_save(saved)
             self._after_save(sid)
         seq.prefill_done += len(chunk)
         if seq.pending_from_gen and self.save_hidden:
@@ -867,9 +877,16 @@ class InferenceEngine:
         if seq.prefill_done >= len(prompt):
             seq.phase = Phase.DECODE
             self._publish_slot(seq)
-            lg = out["logits"]
-            tok = int(sample(lg, temperature=self.temperature)[0])
+            with span(PREFILL_SAMPLE):
+                tok = int(sample(out["logits"],
+                                 temperature=self.temperature)[0])
             self._emit_token(seq, tok)
+        m.prefill_tokens += len(chunk)
+        m.prefill_wall_sum += time.perf_counter() - t0
+
+    def _count_save(self, saved) -> None:
+        self.metrics.saved_tokens += saved.tokens
+        self.metrics.save_d2h_bytes += saved.d2h_bytes
 
     # --------------------------------------------------------------- decode
     def _emit_token(self, seq: SequenceState, tok: int) -> None:
@@ -879,7 +896,6 @@ class InferenceEngine:
             seq.ttft_wall = time.perf_counter() - seq.request.arrival_time
             self.metrics.ttft_wall.append(seq.ttft_wall)
             if seq.restored:
-                self.metrics.ttft_sim.append(seq.restore_sim)
                 self.metrics.ttft_wall_restored.append(seq.ttft_wall)
             else:
                 self.metrics.ttft_wall_cold.append(seq.ttft_wall)
@@ -893,19 +909,24 @@ class InferenceEngine:
         if not active:
             return
         t0 = time.perf_counter()
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        for s in self.slots:
-            if s is not None and s.phase == Phase.DECODE and s.generated:
-                tokens[s.slot, 0] = s.generated[-1]
-        lg, hidden = self.kv.decode(self.params, jnp.asarray(tokens))
-        # inactive slots advanced their length too — undo
-        mask = np.zeros((self.max_batch,), bool)
-        for s in active:
-            mask[s.slot] = True
-        lengths = self.kv.get_lengths()
-        lengths[~mask] -= 1
-        self.kv.set_lengths(lengths)
-        toks = np.asarray(sample(lg, temperature=self.temperature))
+        m = self.metrics
+        with span(DECODE_FORWARD):
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            for s in self.slots:
+                if s is not None and s.phase == Phase.DECODE and s.generated:
+                    tokens[s.slot, 0] = s.generated[-1]
+            lg, hidden = self.kv.decode(self.params, jnp.asarray(tokens))
+        # the first read of the step's results waits for the device
+        with span(DECODE_SAMPLE):
+            toks = np.asarray(sample(lg, temperature=self.temperature))
+        with span(DECODE_FORWARD):
+            # inactive slots advanced their length too — undo
+            mask = np.zeros((self.max_batch,), bool)
+            for s in active:
+                mask[s.slot] = True
+            lengths = self.kv.get_lengths()
+            lengths[~mask] -= 1
+            self.kv.set_lengths(lengths)
         if self.save_hidden and hidden is not None:
             # only truly-active sessions: a session that finished at
             # prefill completion still sits in its slot in DECODE phase
@@ -915,9 +936,10 @@ class InferenceEngine:
             sess = [s.request.session_id if (s is not None
                     and s.slot in active_slots) else None
                     for s in self.slots]
-            h = self.adapter.decode_hidden(hidden)
-            self.metrics.snapshot_cost += self.mgr.save_decode_hidden(
-                sess, np.asarray(h), lengths - 1)
+            with span(DECODE_SAVE, m, "save_wall_sum"):
+                h = np.asarray(self.adapter.decode_hidden(hidden))
+                self.mgr.save_decode_hidden(sess, h, lengths - 1)
+            m.save_d2h_bytes += h.nbytes
         dt = time.perf_counter() - t0
         for s in active:
             self._emit_token(s, int(toks[s.slot]))
@@ -928,15 +950,8 @@ class InferenceEngine:
         for i, s in enumerate(self.slots):
             if s is None or not s.finished():
                 continue
-            sid = s.request.session_id
-            n = s.total_len
-            tail = np.asarray(s.generated[s.tok_saved:-1], np.int32)
             if self.save_hidden:
-                self.mgr.saver.drain()
-                self.mgr.save_session_pause(sid, s.view.snapshot(),
-                                            n - 1, tokens_tail=tail)
-                self._after_save(sid)
-                s.tok_saved = len(s.generated) - 1
+                self._checkpoint(s)
             self._publish_slot(s)
             s.phase = Phase.DONE
             s.view.free()
@@ -948,6 +963,21 @@ class InferenceEngine:
                                      and s.generated[-1] == r.eos_token)
                           else "length")
                 self.on_finish(s, reason)
+
+    def _checkpoint(self, s: SequenceState) -> None:
+        """Dump a resident sequence's restorable state (KV-layer tails,
+        SSM states, token tail) through the predecessor of its last
+        sampled token, whose KV does not exist yet."""
+        sid = s.request.session_id
+        with span(RETIRE_SAVE, self.metrics, "save_wall_sum"):
+            self.mgr.saver.drain()
+            saved = self.mgr.save_session_pause(
+                sid, s.view.snapshot(), s.total_len - 1,
+                tokens_tail=np.asarray(s.generated[s.tok_saved:-1],
+                                       np.int32))
+        self._count_save(saved)
+        self._after_save(sid)
+        s.tok_saved = len(s.generated) - 1
 
     def _after_save(self, sid: str) -> None:
         """On-save capacity hook: a demoted session whose stream was just

@@ -62,6 +62,7 @@ class SequenceState:
     admit_step: int = -1                     # engine step of last admission
     enqueue_step: int = 0                    # engine step of last (re)queue
     #                                          (admission aging baseline)
+    enqueue_time: float = 0.0                # perf_counter of that (re)queue
     pauses: int = 0                          # times evicted mid-stream
     # slot-bound CacheView handle (serving/kv_cache.py); set while the
     # sequence holds a batch slot, None when queued/paused/done

@@ -495,6 +495,12 @@ def test_api_validation_and_metrics_endpoint(setup):
         assert st == 200
         json.dumps(m)                       # whole document serializes
         assert "engine" in m and "router" in m and "pump" in m
+        for k in ("queue_wait_sum", "admitted", "prefill_wall_sum",
+                  "prefill_tokens", "save_wall_sum", "saved_tokens",
+                  "save_d2h_bytes", "restore_read_wall",
+                  "restore_upload_wall", "restore_write_wall",
+                  "restore_h2d_bytes"):
+            assert isinstance(m["engine"][k], (int, float)), k
 
     asyncio.run(main())
     pump.close()

@@ -250,8 +250,9 @@ def test_stale_prefetch_discarded_on_manifest_change(setup):
 
 
 def test_engine_reports_measured_io_on_ssd(setup):
-    """With simulated-SSD devices the executor's striped async reads
-    surface a measured completion time in the engine metrics."""
+    """With simulated-SSD devices the executor's striped reads, uploads
+    and cache writes surface as measured seconds and bytes in the engine
+    metrics."""
     cfg, model, params = setup
     from repro.config.hardware import PAPER_A100 as hw
     store = ChunkStore(make_array("ssd", 4), chunk_tokens=16)
@@ -265,26 +266,34 @@ def test_engine_reports_measured_io_on_ssd(setup):
     engine.submit(Request("io", rng.integers(
         0, cfg.vocab_size, 4).astype(np.int32), max_new_tokens=2))
     engine.run()
-    assert engine.metrics.restore_io_measured > 0
+    m = engine.metrics
+    assert m.restore_read_wall > 0 and m.restore_upload_wall > 0
+    assert m.restore_write_wall > 0
+    # every restored token's hidden row of every layer went to the device
+    # (one group upload, padded to the S-bucket)
+    assert m.restore_h2d_bytes >= (m.restored_tokens * cfg.n_layers
+                                   * cfg.d_model * 2)
 
 
 def test_metrics_ttft_populations(setup):
-    """Simulated TTFT is recorded only for sessions that actually
-    restored; cold starts land in their own population."""
+    """Restored TTFT and restore counters are recorded only for sessions
+    that actually restored; cold starts land in their own population."""
     cfg, model, params = setup
     engine, _ = fresh_engine(setup)
     rng = np.random.default_rng(11)
     engine.submit(Request("cold", rng.integers(
         0, cfg.vocab_size, 10).astype(np.int32), max_new_tokens=2))
     engine.run()
-    assert engine.metrics.ttft_sim == []
     assert len(engine.metrics.ttft_wall_cold) == 1
     assert engine.metrics.ttft_wall_restored == []
+    assert engine.metrics.restored_tokens == 0
+    assert engine.metrics.restore_wall_sum == 0.0
 
     engine.submit(Request("cold", rng.integers(
         0, cfg.vocab_size, 4).astype(np.int32), max_new_tokens=2))
     engine.run()
-    assert len(engine.metrics.ttft_sim) == 1
-    assert engine.metrics.ttft_sim[0] > 0
     assert len(engine.metrics.ttft_wall_restored) == 1
+    assert engine.metrics.ttft_wall_restored[0] > 0
+    assert engine.metrics.restored_tokens > 0
+    assert engine.metrics.restore_wall_sum > 0
     assert len(engine.metrics.ttft_wall_cold) == 1
