@@ -43,10 +43,12 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.trace import PREFILL_GATHER, span
+from repro.distributed import tp as tp_lib
 
 
 class FamilyAdapter:
@@ -58,6 +60,9 @@ class FamilyAdapter:
     kv_names: Optional[Tuple[str, str]] = None
     n_state_blobs: int = 0
     has_cross: bool = False
+    # prefill programs traced so far (a family whose prefill runs eagerly
+    # builds none)
+    prefill_traces: int = 0
 
     def __init__(self, model):
         self.model = model
@@ -134,6 +139,26 @@ class LMAdapter(FamilyAdapter):
     supports_recompute = True
     kv_names = ("k", "v")
 
+    def __init__(self, model):
+        super().__init__(model)
+        from repro.models import transformer as tfm
+        h = model.h
+
+        def program(params, tokens, patches, hist_kv, *, capture_hidden,
+                    hist_len, tp):
+            # runs on a trace only, so it counts programs, not calls;
+            # ``tp`` keys the cache: the seams read the active context
+            # while tracing
+            self.prefill_traces += 1
+            return tfm.lm_forward(params, tokens, h, patch_embeds=patches,
+                                  hist_kv=hist_kv, hist_len=hist_len,
+                                  capture_hidden=capture_hidden,
+                                  emit_kv=True, final_logits_only=True)
+
+        # params are an argument, never a constant baked into the program
+        self._prefill = jax.jit(program, static_argnames=(
+            "capture_hidden", "hist_len", "tp"))
+
     def init(self, rng):
         from repro.models import transformer as tfm
         return tfm.init_lm(rng, self.model.h)
@@ -146,12 +171,13 @@ class LMAdapter(FamilyAdapter):
 
     def prefill(self, params, batch, *, capture_hidden=False,
                 hist_kv=None, hist_len=None):
-        from repro.models import transformer as tfm
-        return tfm.lm_forward(params, batch["tokens"], self.model.h,
-                              patch_embeds=batch.get("patches"),
-                              hist_kv=hist_kv, hist_len=hist_len,
-                              capture_hidden=capture_hidden, emit_kv=True,
-                              final_logits_only=True)
+        """One compiled program per (shapes, ``capture_hidden``,
+        ``hist_len``, active TP context)."""
+        return self._prefill(
+            params, batch["tokens"], batch.get("patches"), hist_kv,
+            capture_hidden=bool(capture_hidden),
+            hist_len=None if hist_len is None else int(hist_len),
+            tp=tp_lib.active())
 
     def decode_step_full(self, params, cache, tokens):
         from repro.models import transformer as tfm

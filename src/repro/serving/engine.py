@@ -157,6 +157,7 @@ class EngineMetrics:
     admitted: int = 0                   # successful placements
     prefill_wall_sum: float = 0.0       # s in _prefill_step, all chunks
     prefill_tokens: int = 0             # prompt tokens those chunks ran
+    prefill_traces: int = 0             # prefill programs those chunks built
     save_wall_sum: float = 0.0          # s in the prefill/decode/retire saves
     saved_tokens: int = 0               # tokens the saves made restorable
     save_d2h_bytes: int = 0             # bytes they copied off the device
@@ -858,9 +859,11 @@ class InferenceEngine:
         m = self.metrics
         chunk = remaining[:self.prefill_chunk] if ad.chunkable else remaining
         hist = seq.history_len + seq.prefill_done
+        traces = ad.prefill_traces
         with span(PREFILL_FORWARD):
             out = ad.prefill_chunk(self.params, seq, chunk, hist,
                                    capture_hidden=self.save_hidden)
+        m.prefill_traces += ad.prefill_traces - traces
         with span(PREFILL_ABSORB):
             ad.absorb_prefill(seq.view, out, len(chunk), hist)
             seq.view.set_length(hist + len(chunk))

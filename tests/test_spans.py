@@ -132,9 +132,9 @@ def test_trace_holds_the_spans_that_ran(setup, tmp_path):
 
 # the running sums a benchmark reader takes differences of over its window
 COUNTERS = ("queue_wait_sum", "admitted", "prefill_wall_sum",
-            "prefill_tokens", "save_wall_sum", "saved_tokens",
-            "save_d2h_bytes", "restore_read_wall", "restore_upload_wall",
-            "restore_write_wall", "restore_h2d_bytes")
+            "prefill_tokens", "prefill_traces", "save_wall_sum",
+            "saved_tokens", "save_d2h_bytes", "restore_read_wall",
+            "restore_upload_wall", "restore_write_wall", "restore_h2d_bytes")
 
 
 @pytest.mark.parametrize("name", COUNTERS)
