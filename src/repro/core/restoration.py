@@ -768,8 +768,9 @@ class RestorationExecutor:
     both surfaced by bench_restore_batch. ``read_wall``, ``upload_wall``
     and ``write_wall`` are the seconds of the executor's store-read,
     host->device and cache-write spans (core/trace.py), ``h2d_bytes``
-    the bytes it handed to the device; the engine folds them into its
-    metrics when the restore completes."""
+    the bytes it handed to the device (``hidden_h2d_bytes`` of them the
+    hidden-state stacks of projection groups); the engine folds them into
+    its metrics when the restore completes."""
 
     def __init__(self, mgr, params, session: str,
                  sink: Optional[RestoreSink] = None, start_token: int = 0):
@@ -896,6 +897,7 @@ class RestorationExecutor:
         self.upload_wall = 0.0
         self.write_wall = 0.0
         self.h2d_bytes = 0
+        self.hidden_h2d_bytes = 0
         self.dispatch_count = 0
         self._enc_out: Optional[np.ndarray] = None
         # online profiling (DESIGN.md §13): per-task observed durations.
@@ -1273,6 +1275,7 @@ class RestorationExecutor:
         with span(RESTORE_UPLOAD, self, "upload_wall"):
             hidden = pack.place_hidden(stack)
         self.h2d_bytes += stack.nbytes
+        self.hidden_h2d_bytes += stack.nbytes
         with span(RESTORE_PROJECT, self, "project_wall"):
             k, v = _project_group_jit(
                 hidden, jnp.asarray(rows_pad), pack.ln_scale, pack.ln_bias,

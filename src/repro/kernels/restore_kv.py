@@ -27,7 +27,16 @@ block is double-buffered by the pipeline, so a cell holds
 default scoped VMEM limit of a v5e core (the remainder is headroom for
 the compiler's own temporaries). qwen2-7b (D=3584, KV=512, bf16) gets
 256×128 tiles (8.0 MiB); llama2-7b (D=4096, KV=4096) 256×128
-(9.0 MiB).
+(9.0 MiB); opt-30b (D=7168, KV=7168, bias) 128×128 (11.0 MiB: a
+256-row tile would need 15.0).
+
+Weight re-read. Each (g, i) cell sweeps every KV tile, so both weight
+stacks, 2·D·KV·itemsize bytes, are read once per BLOCK_S rows: the
+kernel's arithmetic intensity is BLOCK_S flop/byte whatever D and KV
+are. At opt-30b's 128-row tiles that is 26.3 GFLOP over 205 MB a tile,
+128 flop/byte against the v5e ridge of 240 (197 TFLOP/s over 819 GB/s),
+so the kernel cannot pass about 53% of its compute roofline; qwen2-7b's
+256-row tiles allow all of it.
 
 RoPE is the rotate-half form applied per head as
 ``x · [cos, cos] + roll(x, hd/2) · [-sin, sin]``: a lane rotation of one

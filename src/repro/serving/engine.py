@@ -8,14 +8,16 @@ Request lifecycle (paper §5, DESIGN.md §6/§8):
                              bounded number of pipeline tasks per engine
                              step, writing each finished layer straight
                              into the sequence's batch-slot buffers. Any
-                             number of sessions restore concurrently, and
+                             number of sessions restore concurrently,
+                             sharing that per-step task budget, and
                              restoring sessions never block the decode
                              batch of active ones. Queued sessions with
                              stored state get their first hidden-layer IO
                              prefetched before a slot even frees;
             -> PREFILL       chunked prompt prefill (SplitFuse-style: at most
-                             ``prefill_chunk`` prompt tokens per engine step,
-                             so decode iterations stay interleaved);
+                             ``prefill_chunk`` prompt tokens per engine step
+                             over all prefilling sequences, so decode
+                             iterations stay interleaved);
             -> DECODE        joins the continuous decode batch; every step
                              streams the new token's hidden states to the
                              two-stage saver;
@@ -165,6 +167,13 @@ class EngineMetrics:
     restore_upload_wall: float = 0.0    # s handing host arrays to the device
     restore_write_wall: float = 0.0     # s writing restored pieces to cache
     restore_h2d_bytes: int = 0          # bytes restores handed to the device
+    # restored work by method: (layer, token) pairs each method rebuilt,
+    # and the hidden-state part of restore_h2d_bytes (the padded stacks
+    # the projection uploads)
+    restore_hidden_layer_tokens: int = 0
+    restore_kv_layer_tokens: int = 0
+    restore_recompute_layer_tokens: int = 0
+    restore_hidden_h2d_bytes: int = 0
 
     @property
     def restore_bubble_mean(self) -> float:
@@ -221,6 +230,11 @@ class EngineMetrics:
                      "fragmentation_mean"):
             out[prop] = float(getattr(self, prop))
         return out
+
+
+def _admission(seq: SequenceState) -> tuple:
+    """Admission order: engine step of admission, then slot."""
+    return (seq.admit_step, seq.slot)
 
 
 class InferenceEngine:
@@ -306,6 +320,7 @@ class InferenceEngine:
         self._prefetch: Dict[str, object] = {}   # session -> warm executor
         self.metrics = EngineMetrics()
         self.step_count = 0
+        self._prefill_used = 0     # prompt tokens prefilled this step
 
     # ----------------------------------------------------------- submission
     def submit(self, request: Request) -> SequenceState:
@@ -786,22 +801,34 @@ class InferenceEngine:
             load_setter(LinkLoad(streams))
         self.metrics.io_streams_peak = max(self.metrics.io_streams_peak, n)
 
+    def _in_phase(self, phase: Phase) -> List[SequenceState]:
+        """Resident sequences in ``phase``, earliest admitted first."""
+        return sorted((s for s in self.slots
+                       if s is not None and s.phase == phase),
+                      key=_admission)
+
     def _restore_step(self) -> None:
-        """Advance every RESTORING session by a bounded number of pipeline
-        tasks. Several sessions restore concurrently; the decode batch of
-        active sessions runs in the same engine step regardless."""
+        """Advance the RESTORING sessions by ``restore_tasks_per_step``
+        pipeline tasks in all, earliest admitted first: several sessions
+        restore concurrently, but a burst of admissions shares one step's
+        budget instead of stalling the decode batch, which runs in the
+        same engine step regardless, for all of their restores at once."""
         m = self.metrics
-        for seq in self.slots:
-            if seq is None or seq.phase != Phase.RESTORING:
-                continue
-            if seq.executor.step(self.restore_tasks_per_step):
+        budget = self.restore_tasks_per_step
+        for seq in self._in_phase(Phase.RESTORING):
+            if budget <= 0:
+                break
+            ran = len(seq.executor.executed)
+            done = seq.executor.step(budget)
+            budget -= len(seq.executor.executed) - ran
+            if done:
                 ex = seq.executor
                 seq.executor = None
                 seq.restored = True
                 seq.history_len = ex.n_tokens
                 seq.restore_sim = ex.timeline().makespan
                 seq.restore_wall = ex.wall_time
-                m.restored_tokens += ex.n_tokens - ex.start_token
+                m.restored_tokens += ex.n_eff
                 m.restore_sim_all.append(seq.restore_sim)
                 if seq.pending_from_gen:       # resume of a paused session
                     m.restore_sim_resume.append(seq.restore_sim)
@@ -811,6 +838,12 @@ class InferenceEngine:
                 m.restore_upload_wall += ex.upload_wall
                 m.restore_write_wall += ex.write_wall
                 m.restore_h2d_bytes += ex.h2d_bytes
+                m.restore_hidden_layer_tokens += \
+                    ex.n_eff * ex.methods.count("hidden")
+                m.restore_kv_layer_tokens += ex.n_eff * ex.methods.count("kv")
+                m.restore_recompute_layer_tokens += \
+                    ex.n_eff * ex.methods.count("recompute")
+                m.restore_hidden_h2d_bytes += ex.hidden_h2d_bytes
                 self._record_calibration(ex)
                 seq.phase = Phase.PREFILL
 
@@ -842,7 +875,10 @@ class InferenceEngine:
     def _prefill_step(self, seq: SequenceState) -> None:
         """Process up to ``prefill_chunk`` prompt tokens (SplitFuse;
         families whose adapter is not ``chunkable`` — recurrent-state and
-        enc-dec stacks — take the whole prompt in one step).
+        enc-dec stacks — take the whole prompt in one step). The budget
+        is the step's, shared by every prefilling sequence: a chunk runs
+        whole, and after the step's first chunk only if it fits in what
+        is left, so a chunk's shape never depends on the budget.
 
         After a mid-stream eviction the "prompt" is the resume feed
         (``effective_prompt``): the last sampled token, whose KV is
@@ -855,9 +891,13 @@ class InferenceEngine:
         if len(remaining) == 0:
             seq.phase = Phase.DECODE
             return
+        chunk = remaining[:self.prefill_chunk] if ad.chunkable else remaining
+        if self._prefill_used and \
+                self._prefill_used + len(chunk) > self.prefill_chunk:
+            return
+        self._prefill_used += len(chunk)
         t0 = time.perf_counter()
         m = self.metrics
-        chunk = remaining[:self.prefill_chunk] if ad.chunkable else remaining
         hist = seq.history_len + seq.prefill_done
         traces = ad.prefill_traces
         with span(PREFILL_FORWARD):
@@ -1026,15 +1066,31 @@ class InferenceEngine:
 
     def step(self) -> None:
         self.step_count += 1
+        self._prefill_used = 0
         # refresh the planner's view of restore contention (completed
         # restores lower the multiplicity; admission below may raise it)
         self._update_io_streams()
         self._admit()
         self._maybe_preempt()
-        self._restore_step()
+        # restore tasks and prefill chunks share a step only while no
+        # sequence decodes: either can take longer than a decode step, so
+        # with a decode batch waiting the step runs the kind whose
+        # earliest sequence was admitted first, and the other kind waits
+        # a step (a restored session's first chunk included)
+        restoring = self._in_phase(Phase.RESTORING)
+        decoding = any(s is not None and s.phase == Phase.DECODE
+                       and not s.finished() for s in self.slots)
+        run_restore = bool(restoring)
+        if decoding and restoring:
+            prefilling = self._in_phase(Phase.PREFILL)
+            run_restore = not self._prefill_used and not (
+                prefilling and _admission(prefilling[0])
+                < _admission(restoring[0]))
+        if run_restore:
+            self._restore_step()
         prefilled = False
-        for s in list(self.slots):
-            if s is not None and s.phase == Phase.PREFILL:
+        if not (decoding and run_restore):
+            for s in self._in_phase(Phase.PREFILL):
                 self._prefill_step(s)
                 prefilled = True
         decoded_before = self.metrics.decode_steps

@@ -499,7 +499,10 @@ def test_api_validation_and_metrics_endpoint(setup):
                   "prefill_tokens", "prefill_traces", "save_wall_sum",
                   "saved_tokens", "save_d2h_bytes", "restore_read_wall",
                   "restore_upload_wall", "restore_write_wall",
-                  "restore_h2d_bytes"):
+                  "restore_h2d_bytes", "restore_hidden_layer_tokens",
+                  "restore_kv_layer_tokens",
+                  "restore_recompute_layer_tokens",
+                  "restore_hidden_h2d_bytes"):
             assert isinstance(m["engine"][k], (int, float)), k
 
     asyncio.run(main())

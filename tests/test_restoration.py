@@ -194,6 +194,80 @@ def test_two_sessions_restore_concurrently(setup):
     assert len(engine.result("a")) == 2 and len(engine.result("b")) == 2
 
 
+def test_concurrent_restores_share_the_step_budget(setup):
+    """Sessions admitted in one step share ``restore_tasks_per_step``:
+    no step runs more restore tasks than that, the earliest admitted
+    session goes first, and both restore in full."""
+    cfg, model, params = setup
+    engine, mgr = fresh_engine(setup, max_batch=3, restore_tasks_per_step=3)
+    rng = np.random.default_rng(19)
+    for sid in ("a", "b"):
+        engine.submit(Request(sid, rng.integers(
+            0, cfg.vocab_size, 24).astype(np.int32), max_new_tokens=2))
+    engine.run()
+    for sid in ("a", "b"):
+        engine.submit(Request(sid, rng.integers(
+            0, cfg.vocab_size, 4).astype(np.int32), max_new_tokens=2))
+    seen = {}
+    per_step = []
+    while any(s is not None for s in engine.slots) or engine.queue:
+        before = {sid: len(ex.executed) for sid, ex in seen.items()}
+        engine.step()
+        for sid in ("a", "b"):
+            ex = engine.sessions[sid].executor
+            if ex is not None:
+                seen[sid] = ex
+        per_step.append({sid: len(ex.executed) - before.get(sid, 0)
+                         for sid, ex in seen.items()})
+    n_tasks = {sid: len(ex.tasks) for sid, ex in seen.items()}
+    assert min(n_tasks.values()) > 3          # each restore spans steps
+    assert all(sum(d.values()) <= 3 for d in per_step)
+    first = per_step[0]
+    assert first.get("a", 0) == 3 and first.get("b", 0) == 0
+    assert engine.sessions["a"].restored and engine.sessions["b"].restored
+    assert len(engine.result("a")) == 2 and len(engine.result("b")) == 2
+
+
+def test_restore_and_prefill_share_a_step_only_without_decode(setup):
+    """A restored session's first chunk runs in the step that finished
+    its restore only while no sequence decodes; with a decode batch
+    waiting it runs in the next step, and the batch still decodes every
+    step."""
+    cfg, model, params = setup
+    engine, mgr = fresh_engine(setup, max_batch=2)
+    rng = np.random.default_rng(29)
+
+    def turn(sid, n, new):
+        return engine.submit(Request(sid, rng.integers(
+            0, cfg.vocab_size, n).astype(np.int32), max_new_tokens=new))
+
+    def step_tokens():
+        before = engine.metrics.prefill_tokens
+        engine.step()
+        return engine.metrics.prefill_tokens - before
+
+    turn("r", 24, 2)
+    engine.run()
+    seq = turn("r", 4, 3)                 # alone: one step does both
+    while not seq.restored:
+        n = step_tokens()
+    assert n == 4 and seq.phase == Phase.DECODE
+    engine.run()
+
+    d = turn("d", 6, 40)
+    while d.phase != Phase.DECODE:
+        engine.step()
+    seq = turn("r", 4, 3)
+    while not seq.restored:
+        before = len(d.generated)
+        n = step_tokens()
+        assert len(d.generated) == before + 1
+    assert n == 0 and seq.phase == Phase.PREFILL
+    assert step_tokens() == 4 and seq.phase == Phase.DECODE
+    engine.run()
+    assert len(engine.result("r")) == 3
+
+
 def test_prefetch_starts_before_slot_frees(setup):
     """A queued session with stored state gets IO prefetched while all
     slots are still busy."""

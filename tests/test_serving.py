@@ -51,6 +51,37 @@ def test_continuous_batching_mixed_lengths(setup):
     assert len(engine.result("b")) == 9
 
 
+def test_prefill_budget_is_per_step(setup):
+    """Sequences prefilling together share ``prefill_chunk`` prompt
+    tokens a step: whole chunks, earliest admitted first, a later chunk
+    only where it fits, and the answers equal those served alone."""
+    cfg, model, params = setup
+    rng = np.random.default_rng(23)
+    prompts = {"a": rng.integers(0, cfg.vocab_size, 20).astype(np.int32),
+               "b": rng.integers(0, cfg.vocab_size, 3).astype(np.int32),
+               "c": rng.integers(0, cfg.vocab_size, 4).astype(np.int32)}
+    alone = {}
+    for sid, p in prompts.items():
+        e, _ = fresh_engine(setup, max_batch=3)
+        e.submit(Request(sid, p, max_new_tokens=3))
+        e.run()
+        alone[sid] = e.result(sid)
+    engine, _ = fresh_engine(setup, max_batch=3)
+    for sid, p in prompts.items():
+        engine.submit(Request(sid, p, max_new_tokens=3))
+    per_step = []
+    while any(s is not None for s in engine.slots) or engine.queue:
+        before = engine.metrics.prefill_tokens
+        engine.step()
+        per_step.append(engine.metrics.prefill_tokens - before)
+    # a: chunks of 8, 8, 4; b and c fit beside a's 4-token tail only
+    assert per_step[:3] == [8, 8, 4 + 3]
+    assert all(n <= 8 for n in per_step)
+    assert sum(per_step) == sum(len(p) for p in prompts.values())
+    for sid in prompts:
+        assert engine.result(sid) == alone[sid]
+
+
 def test_multi_round_restoration_matches_no_eviction(setup):
     """Round-2 generation after evict+restore == never-evicted decoding."""
     cfg, model, params = setup

@@ -102,6 +102,52 @@ def test_counters_over_save_evict_restore(setup):
     assert m.save_wall_sum > 0.0
 
 
+def test_restore_counters_by_method():
+    """A tiny OPT session saved under a plan that mixes all three methods,
+    then restored by the engine: each by-method counter is exact. The
+    hidden layers form one projection group and the history is a whole
+    projection bucket, so the hidden upload carries no padding."""
+    from repro.core.restoration import s_bucket
+    from repro.core.scheduler import Schedule
+    from repro.distributed.sharding import default_rules
+    from repro.launch.mesh import make_mesh
+    cfg = reduced_for_smoke(get_arch("opt-30b"))
+    model = Model(cfg, rules=default_rules(make_mesh((1, 1),
+                                                     ("data", "model"))),
+                  model_axis=1, dtype=jnp.float32, remat="none")
+    params, _ = split(model.init(jax.random.PRNGKey(1)))
+    methods = ("recompute", "hidden", "kv", "hidden")
+    assert cfg.n_layers == len(methods)
+    mgr = HCacheManager(model, ChunkStore(make_array("dram", 2),
+                                          chunk_tokens=16),
+                        hw=PAPER_A100, store_dtype=np.float32)
+    mgr.plan = lambda n_tokens: Schedule(methods, 0.0, 0.0, 0.0, 0.0)
+    engine = InferenceEngine(model, params, mgr, max_batch=BATCH,
+                             max_seq=128, prefill_chunk=8, backend="paged")
+    rng = np.random.default_rng(11)
+    n = 32
+    assert s_bucket(n) == n
+    hist = rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+    out = model.prefill(params, {"tokens": jnp.asarray(hist)[None]},
+                        capture_hidden=True)
+    mgr.save_prefill("opt", hist, out, start=0)
+    mgr.saver.drain()
+    engine.submit(Request("opt", rng.integers(0, cfg.vocab_size, 5)
+                          .astype(np.int32), max_new_tokens=3))
+    engine.run()
+    engine.close()
+    assert engine.sessions["opt"].restored
+    m = engine.metrics
+    isz = 4                                    # float32 store and model
+    kv_row = 2 * cfg.n_kv_heads * cfg.head_dim_ * isz
+    assert m.restored_tokens == n
+    assert m.restore_hidden_layer_tokens == n * 2
+    assert m.restore_kv_layer_tokens == n * 1
+    assert m.restore_recompute_layer_tokens == n * 1
+    assert m.restore_hidden_h2d_bytes == n * 2 * cfg.d_model * isz
+    assert m.restore_h2d_bytes == m.restore_hidden_h2d_bytes + n * kv_row
+
+
 def test_trace_holds_the_spans_that_ran(setup, tmp_path):
     from bench.harness import trace as bench_trace
     cfg, _, params = setup
@@ -134,7 +180,9 @@ def test_trace_holds_the_spans_that_ran(setup, tmp_path):
 COUNTERS = ("queue_wait_sum", "admitted", "prefill_wall_sum",
             "prefill_tokens", "prefill_traces", "save_wall_sum",
             "saved_tokens", "save_d2h_bytes", "restore_read_wall",
-            "restore_upload_wall", "restore_write_wall", "restore_h2d_bytes")
+            "restore_upload_wall", "restore_write_wall", "restore_h2d_bytes",
+            "restore_hidden_layer_tokens", "restore_kv_layer_tokens",
+            "restore_recompute_layer_tokens", "restore_hidden_h2d_bytes")
 
 
 @pytest.mark.parametrize("name", COUNTERS)

@@ -19,9 +19,11 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 G, S, HD = 8, 2048, 128
-# (name, d_model, KV = n_kv_heads * head_dim, QKV bias)
-WIDTHS = [("qwen2-7b", 3584, 4 * HD, True),
-          ("llama2-7b", 4096, 32 * HD, False)]
+# (name, d_model, KV = n_kv_heads * head_dim, QKV bias, RoPE, the tile
+# (BLOCK_S, BLOCK_KV) the kernel takes)
+WIDTHS = [("qwen2-7b", 3584, 4 * HD, True, True, (256, 128)),
+          ("llama2-7b", 4096, 32 * HD, False, True, (256, 128)),
+          ("opt-30b", 7168, 56 * HD, True, False, (128, 128))]
 
 
 @pytest.fixture(scope="module")
@@ -58,9 +60,10 @@ def test_v5e_kind_has_a_profile(topo):
     assert profile_for_device(topo.devices[0]) is TPU_V5E
 
 
-@pytest.mark.parametrize("name,D,KV,bias", WIDTHS,
+@pytest.mark.parametrize("name,D,KV,bias,rope,tile", WIDTHS,
                          ids=[w[0] for w in WIDTHS])
-def test_restore_kernel_compiles_at_real_width(one_chip, name, D, KV, bias):
+def test_restore_kernel_compiles_at_real_width(one_chip, name, D, KV, bias,
+                                               rope, tile):
     from repro.kernels.restore_kv import (VMEM_BUDGET, _pick_blocks,
                                           _vmem_bytes,
                                           restore_kv_grouped_pallas)
@@ -72,9 +75,10 @@ def test_restore_kernel_compiles_at_real_width(one_chip, name, D, KV, bias):
     compiled = restore_kv_grouped_pallas.lower(
         sds((G, S, D)), sds((G, D, KV)), sds((G, D, KV)), b, b,
         sds((S, HD // 2), jnp.float32), sds((S, HD // 2), jnp.float32),
-        head_dim=HD, interpret=False).compile()
+        head_dim=HD, use_rope=rope, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
     bs, bkv = _pick_blocks(S, KV, D, HD, 2, bias, 256, 0)
+    assert (bs, bkv) == tile
     assert _vmem_bytes(bs, bkv, D, HD, 2, bias) <= VMEM_BUDGET
 
 
