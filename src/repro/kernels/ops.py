@@ -10,8 +10,9 @@ from __future__ import annotations
 import jax
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import (decode_attention_paged_pallas,
-                                            decode_attention_pallas)
+from repro.kernels.decode_attention import (decode_attention_pallas,
+                                            paged_decode_attention_pallas,
+                                            pages_fit_tiles)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.restore_kv import restore_kv_grouped_pallas
 from repro.kernels.ssm_update import ssm_update_pallas
@@ -84,21 +85,23 @@ def decode_attention(q, k, v, kv_len, *, softcap=None, window=None,
                                    window=window, interpret=interpret)
 
 
-def decode_attention_paged(q, k_pool, v_pool, block_table, kv_len, *,
-                           softcap=None, window=None, use_pallas=True,
-                           interpret=None, head_sharding=None):
-    """Paged (block-table) decode attention — see decode_attention.py.
-    ``head_sharding`` partitions the launch head-parallel over a
-    tensor-parallel mesh (kernel path; the jnp oracle ignores it — its
-    sharding comes from constraint propagation in the caller)."""
+def decode_attention_paged(q, k_new, v_new, k_pool, v_pool, block_table,
+                           lengths, layer, window=None, *, softcap=None,
+                           use_pallas=True, interpret=None,
+                           head_sharding=None):
+    """Paged decode attention over the pool read in place — see
+    decode_attention.py. ``head_sharding`` runs the kernel head-parallel
+    over a tensor-parallel mesh (kernel path; the jnp oracle takes its
+    sharding from the caller's constraints)."""
     if not use_pallas:
         return ref.decode_attention_paged_ref(
-            q, k_pool, v_pool, block_table, kv_len, softcap=softcap,
-            window=window)
+            q, k_new, v_new, k_pool, v_pool, block_table, lengths, layer,
+            window, softcap=softcap)
     interpret = (not on_tpu()) if interpret is None else interpret
-    return decode_attention_paged_pallas(
-        q, k_pool, v_pool, block_table, kv_len, softcap=softcap,
-        window=window, interpret=interpret, head_sharding=head_sharding)
+    return paged_decode_attention_pallas(
+        q, k_new, v_new, k_pool, v_pool, block_table, lengths, layer,
+        window, softcap=softcap, interpret=interpret,
+        head_sharding=head_sharding)
 
 
 def ssm_update(h, dt, x, A, B, C, d_skip, *, use_pallas=True,

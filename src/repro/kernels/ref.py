@@ -91,20 +91,35 @@ def decode_attention_ref(q, k, v, kv_len, *, softcap=None, window=None):
         q.dtype)
 
 
-def decode_attention_paged_ref(q, k_pool, v_pool, block_table, kv_len, *,
-                               softcap=None, window=None):
-    """jnp oracle for the paged kernel: materialize the logical layout by
-    block-table gather (sentinel entries clamp; whatever they alias lies
-    past ``kv_len`` and carries exactly-zero probability), then reuse the
-    contiguous decode oracle. q (BKv,G,hd); pools (NB,bs,hd);
-    block_table (BKv,MB); kv_len (BKv,)."""
-    NB, bs, hd = k_pool.shape
-    BKv, MB = block_table.shape
+def decode_attention_paged_ref(q, k_new, v_new, k_pool, v_pool,
+                               block_table, lengths, layer, window=None, *,
+                               softcap=None):
+    """jnp oracle for the paged kernel, by the plain route: gather the
+    layer's logical layout through the block table (sentinel entries
+    clamp; whatever they alias lies past the live length and carries
+    exactly-zero probability), put the new token at ``lengths`` (dropped
+    when the table is full, as the cache write drops it), then the
+    contiguous decode oracle per KV head. q (B,Hp,hd); k_new/v_new
+    (B,Kv,hd); pools (L,NB,bs,Kv,hd); block_table (B,MB); lengths (B,)
+    tokens already in the pool."""
+    L, NB, bs, Kv, hd = k_pool.shape
+    B, MB = block_table.shape
+    Hp = q.shape[1]
+    g = Hp // Kv
+    S = MB * bs
     tbl = jnp.minimum(block_table.astype(jnp.int32), NB - 1)
-    k = k_pool[tbl].reshape(BKv, MB * bs, hd)
-    v = v_pool[tbl].reshape(BKv, MB * bs, hd)
-    return decode_attention_ref(q, k, v, kv_len, softcap=softcap,
-                                window=window)
+    bidx = jnp.arange(B)
+
+    def logical(pool, new):
+        x = pool[layer][tbl].reshape(B, S, Kv, hd)
+        x = x.at[bidx, lengths].set(new, mode="drop")
+        return x.transpose(0, 2, 1, 3).reshape(B * Kv, S, hd)
+
+    out = decode_attention_ref(
+        q.reshape(B * Kv, g, hd), logical(k_pool, k_new),
+        logical(v_pool, v_new), jnp.repeat(lengths + 1, Kv),
+        softcap=softcap, window=window)
+    return out.reshape(B, Hp, hd)
 
 
 def ssm_update_ref(h, dt, x, A, B, C, d_skip):

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.config.arch import ArchConfig
 from repro.distributed import tp as tp_lib
 from repro.distributed.sharding import ShardingRules, constrain, pad_heads
+from repro.kernels import ops
 from repro.models.layers import attention as attn_lib
 from repro.models.layers.attention import AttnHyper
 from repro.models.layers.embedding import (embed_tokens, init_embedding,
@@ -207,44 +208,60 @@ def block_decode(p, x, h: LMHyper, *, k_cache, v_cache, lengths, window):
     return x, k_cache, v_cache, hidden_in
 
 
-def block_decode_paged(p, x, h: LMHyper, *, k_pool, v_pool, block_table,
-                       blk, off, lengths, window):
-    """Single-token block over a paged KV cache.
+def block_decode_paged(p, x, h: LMHyper, *, k_pool, v_pool, layer,
+                       block_table, lengths, window):
+    """Single-token block over a paged KV cache read in place.
 
-    x: (B,1,D); pools (NB, bs, Kv, hd) physical pages; block_table
-    (B, MB) logical→physical page map (entries >= NB are unallocated
-    sentinels); blk/off (B,) precomputed write address of the new token.
-    The new KV is scattered into its page (sentinel writes drop), then
-    attention runs over the block-table gather of the logical layout —
-    identical math to ``block_decode``: masked positions contribute
-    exactly-zero probability, so gathered junk past the live length
-    cannot perturb the output."""
+    x: (B,1,D); pools (L, NB, bs, Kv, hd), the whole stack, read at
+    ``layer`` and never written here; block_table (B, MB) logical →
+    physical page map (entries >= NB are unallocated sentinels); lengths
+    (B,) tokens already in the pool. The new token's K/V are attended
+    as an operand and returned for the caller's one scatter after the
+    layer scan. Returns (x_out, k_new, v_new, hidden_in), k/v (B,Kv,hd).
+
+    On the TPU this is the paged kernel (kernels/decode_attention.py):
+    it DMAs only each slot's live pages. Elsewhere, and where a page's
+    KV heads do not fill the pool's tiles (``pages_fit_tiles``: bf16
+    with one KV head per device, say), it gathers the layer's logical
+    layout by table and runs ``decode_attention_jnp`` —
+    the contiguous step's arithmetic, so paged and contiguous decode
+    agree bitwise there (masked positions carry exactly-zero
+    probability, so gathered junk past the live length cannot perturb
+    the output)."""
     c = h.cfg
     hidden_in = x
     positions = lengths[:, None]                       # (B,1)
     normed = apply_norm(p["ln1"], x, c.norm, c.norm_eps)
     q, k, v = attn_lib.project_qkv(p["attn"], normed, h.attn, h.rules,
                                    positions)
-    k_pool = k_pool.at[blk, off].set(k[:, 0], mode="drop")
-    v_pool = v_pool.at[blk, off].set(v[:, 0], mode="drop")
-    k_pool = constrain(k_pool, h.rules, None, None, "kv_heads", "head_dim")
-    v_pool = constrain(v_pool, h.rules, None, None, "kv_heads", "head_dim")
-    # tensor-parallel seam (DESIGN.md §16): under an active TPContext the
-    # pools stay sharded over KV heads — the new-token scatter and the
-    # block-table gather below never index the head axis, so both are
-    # shard-local by construction
-    k_pool = tp_lib.kv_seam(k_pool, 2)
-    v_pool = tp_lib.kv_seam(v_pool, 2)
     B, MB = block_table.shape
-    NB, bs = k_pool.shape[0], k_pool.shape[1]
-    table = jnp.minimum(block_table, NB - 1)           # clamp sentinels
-    k_cache = k_pool[table].reshape(B, MB * bs, *k_pool.shape[2:])
-    v_cache = v_pool[table].reshape(B, MB * bs, *v_pool.shape[2:])
-    w = None
-    if window is not None:
-        w = window if not isinstance(window, int) else jnp.asarray(window)
-    attn_out = attn_lib.decode_attention_jnp(
-        q, k_cache, v_cache, h.attn, kv_len=lengths + 1, window=w)
+    Hp, hd = q.shape[2], q.shape[3]
+    ctx = tp_lib.active()
+    kv_dev = k_pool.shape[3] // (1 if ctx is None else ctx.tp)
+    if ops.on_tpu() and ops.pages_fit_tiles(kv_dev, k_pool.dtype):
+        attn_out = ops.decode_attention_paged(
+            q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool, block_table,
+            lengths, layer, window, softcap=c.attn_softcap,
+            head_sharding=None if ctx is None else ctx.kv_sharding(3, 1))
+        attn_out = attn_out.reshape(B, 1, Hp, hd)
+    else:
+        NB, bs = k_pool.shape[1], k_pool.shape[2]
+        table = jnp.minimum(block_table, NB - 1)       # clamp sentinels
+        bidx = jnp.arange(B)
+
+        def logical(pool, new):
+            cache = pool[layer][table].reshape(B, MB * bs, *pool.shape[3:])
+            cache = cache.at[bidx, lengths].set(new[:, 0], mode="drop")
+            cache = constrain(cache, h.rules, None, None, "kv_heads",
+                              "head_dim")
+            # tensor-parallel seam (DESIGN.md §16): the gather and the
+            # token write never index the head axis, so under an active
+            # TPContext both stay shard-local
+            return tp_lib.kv_seam(cache, 2)
+
+        attn_out = attn_lib.decode_attention_jnp(
+            q, logical(k_pool, k), logical(v_pool, v), h.attn,
+            kv_len=lengths + 1, window=window)
     # the ONE collective of the sharded decode path: replicate the
     # per-head attention output before the wo contraction so the output
     # projection (and the logits) run the exact single-device program —
@@ -260,7 +277,7 @@ def block_decode_paged(p, x, h: LMHyper, *, k_pool, v_pool, block_table,
     if c.post_attn_norm:
         ff = apply_norm(p["post_ln2"], ff, c.norm, c.norm_eps)
     x = x + ff
-    return x, k_pool, v_pool, hidden_in
+    return x, k[:, 0], v[:, 0], hidden_in
 
 
 def _remat_wrap(fn, h: LMHyper):
@@ -362,39 +379,46 @@ def lm_decode_step_paged(params, cache, tokens, h: LMHyper):
 
     cache: dict(k_pool/v_pool (L, NB, bs, Kv, hd), block_table (B, MB)
     int32, lengths (B,)). tokens: (B,1). Returns (logits, new cache,
-    per-layer hidden) — same contract as ``lm_decode_step``; with every
-    live position mapped by the block table this is byte-identical to
-    the contiguous step at logical width MB·bs == Smax."""
+    per-layer hidden) — same contract as ``lm_decode_step``.
+
+    The pools are read in place: the layer scan carries only the
+    activations, and each layer's attention reads its slice of the
+    stacked pools by layer index. The new token's K/V come out of the
+    scan, (L, B, Kv, hd), and land in their pages in one scatter after
+    it (sentinel addresses drop); under a donated cache XLA makes that
+    scatter in place, so no pool-sized copy is left in the step."""
     c = h.cfg
     lengths = cache["lengths"]
     bt = cache["block_table"]
-    bs = cache["k_pool"].shape[2]
+    k_pool, v_pool = cache["k_pool"], cache["v_pool"]
+    L, NB, bs = k_pool.shape[:3]
     x = _embed_input(params, h, tokens, lengths[:, None])
     x = constrain(x, h.rules, "batch", None, "d_model")
     windows = layer_windows(h)
     B = tokens.shape[0]
     MB = bt.shape[1]
-    NB = cache["k_pool"].shape[1]
+
+    def body(x, xs):
+        bp, win, layer = xs
+        x, nk, nv, hidden = block_decode_paged(
+            bp, x, h, k_pool=k_pool, v_pool=v_pool, layer=layer,
+            block_table=bt, lengths=lengths, window=win)
+        return x, (nk, nv, hidden)
+
+    xs = (params["blocks"], windows, jnp.arange(L, dtype=jnp.int32))
+    x, (nk, nv, hidden) = jax.lax.scan(body, x, xs)
     bidx = jnp.arange(B)
     li = lengths // bs
     # a logical page past the table (slot exactly full) must become a
     # dropped sentinel write, not clamp into the slot's last live page
     blk = jnp.where(li < MB, bt[bidx, jnp.minimum(li, MB - 1)], NB)
     off = lengths % bs
-
-    def body(x, xs):
-        bp, win, kp, vp = xs
-        x, nk, nv, hidden = block_decode_paged(
-            bp, x, h, k_pool=kp, v_pool=vp, block_table=bt, blk=blk,
-            off=off, lengths=lengths, window=win)
-        return x, (nk, nv, hidden)
-
-    xs = (params["blocks"], windows, cache["k_pool"], cache["v_pool"])
-    x, (nk, nv, hidden) = jax.lax.scan(body, x, xs)
+    k_pool = tp_lib.kv_seam(k_pool.at[:, blk, off].set(nk, mode="drop"), 3)
+    v_pool = tp_lib.kv_seam(v_pool.at[:, blk, off].set(nv, mode="drop"), 3)
     x = apply_norm(params["final_norm"], x, c.norm, c.norm_eps)
     lg = embed_logits(params["embed"], x, h.rules, softcap=c.logit_softcap,
                       true_vocab=c.vocab_size)
-    new_cache = {"k_pool": nk, "v_pool": nv, "block_table": bt,
+    new_cache = {"k_pool": k_pool, "v_pool": v_pool, "block_table": bt,
                  "lengths": lengths + 1}
     return lg, new_cache, hidden
 

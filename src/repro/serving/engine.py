@@ -174,6 +174,10 @@ class EngineMetrics:
     restore_kv_layer_tokens: int = 0
     restore_recompute_layer_tokens: int = 0
     restore_hidden_h2d_bytes: int = 0
+    # KV bytes paged decode attention read from the pool: every slot's
+    # tokens before the step, times the backend's K+V bytes a token over
+    # all layers (kv_token_bytes; 0 on backends that read whole buffers)
+    decode_kv_bytes: int = 0
 
     @property
     def restore_bubble_mean(self) -> float:
@@ -968,6 +972,8 @@ class InferenceEngine:
             for s in active:
                 mask[s.slot] = True
             lengths = self.kv.get_lengths()
+            m.decode_kv_bytes += (int(np.maximum(lengths - 1, 0).sum())
+                                  * self.kv.kv_token_bytes)
             lengths[~mask] -= 1
             self.kv.set_lengths(lengths)
         if self.save_hidden and hidden is not None:

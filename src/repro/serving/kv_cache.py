@@ -43,14 +43,17 @@ Consumers all go through a slot-bound ``CacheView`` handle:
 ``ViewSink`` adapts a ``CacheView`` to the restoration executor's
 ``RestoreSink`` protocol — the sink is layout-agnostic; the paged backend
 lands restored layers as whole pages, the contiguous one as a donated
-``dynamic_update_slice``. Decode runs through ``backend.decode`` (the
-paged path gathers pages by block table inside the jitted step — see
-``transformer.lm_decode_step_paged`` and the Pallas kernel in
-``kernels/decode_attention.py``).
+``dynamic_update_slice``. Decode runs through ``backend.decode``: the
+paged step reads the pool in place by block table — on the TPU through
+the Pallas kernel in ``kernels/decode_attention.py``, which fetches only
+each slot's live pages — and writes the new tokens with one scatter
+after its layer scan, into the donated pool (see
+``transformer.lm_decode_step_paged``).
 
-Greedy equivalence: masked attention probabilities are exactly zero past
-the live length, so a paged gather at the same logical width is
-byte-identical to the contiguous layout (tested in tests/test_paged.py).
+Greedy equivalence: off the TPU the paged step gathers the logical
+layout and runs the contiguous step's attention; masked probabilities
+are exactly zero past the live length, so it is byte-identical to the
+contiguous layout (tested in tests/test_paged.py).
 """
 from __future__ import annotations
 
@@ -265,6 +268,13 @@ class KVCacheBackend:
 
     def get_lengths(self) -> np.ndarray:
         raise NotImplementedError
+
+    @property
+    def kv_token_bytes(self) -> int:
+        """K+V bytes one cached token holds over every layer, where decode
+        attention reads only the live history (the paged backends); 0
+        where it reads whole buffers."""
+        return 0
 
     def set_lengths(self, lengths: np.ndarray) -> None:
         raise NotImplementedError
@@ -685,7 +695,11 @@ class PagedBackend(KVCacheBackend):
         # index are reclaimable under pressure (see _alloc_pages)
         self.prefix_index = None
         self.cow_copies = 0
-        self._decode_fn = jax.jit(model.decode_step_paged)
+        # the cache is donated: the step reads the pools in place and
+        # writes the new tokens into them with one scatter, so it holds
+        # one copy of the pool, not two
+        self._decode_fn = jax.jit(model.decode_step_paged,
+                                  donate_argnums=(1,))
         # donated in-place page scatter, retraced per restored length n
         self._write_layer = jax.jit(
             lambda pool, val, row, blk, off:
@@ -853,6 +867,11 @@ class PagedBackend(KVCacheBackend):
 
     def get_lengths(self):
         return np.array(self.cache["lengths"], copy=True)
+
+    @property
+    def kv_token_bytes(self):
+        L, _, _, Kv, hd = self.cache["k_pool"].shape
+        return 2 * L * Kv * hd * self.cache["k_pool"].dtype.itemsize
 
     def set_lengths(self, lengths):
         self.cache["lengths"] = jnp.asarray(lengths, jnp.int32)

@@ -502,7 +502,7 @@ def test_api_validation_and_metrics_endpoint(setup):
                   "restore_h2d_bytes", "restore_hidden_layer_tokens",
                   "restore_kv_layer_tokens",
                   "restore_recompute_layer_tokens",
-                  "restore_hidden_h2d_bytes"):
+                  "restore_hidden_h2d_bytes", "decode_kv_bytes"):
             assert isinstance(m["engine"][k], (int, float)), k
 
     asyncio.run(main())

@@ -187,41 +187,115 @@ def test_decode_attention_sweep(G, Smax, dtype):
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
+def _paged_case(lengths, G, Kv, hd, bs, MB, dtype, L=2):
+    """A paged decode input and its dense reference. Each slot's live
+    pages land at permuted places in a pool full of junk, its other
+    table entries are out-of-range sentinels, and the new token goes to
+    the dense side at position ``lengths`` only (a full table drops
+    it). Returns (args for decode_attention_paged, dense k, v, kv_len),
+    k/v per KV head: (B·Kv, MB·bs, hd)."""
+    B, S = len(lengths), MB * bs
+    Hp = G * Kv
+    NB = B * MB + 3
+    q = jnp.asarray(RNG.normal(size=(B, Hp, hd)), dtype)
+    new = [jnp.asarray(RNG.normal(size=(B, Kv, hd)), dtype)
+           for _ in range(2)]
+    dense = [np.zeros((B, S, Kv, hd)) for _ in range(2)]
+    pools = [RNG.normal(size=(L, NB, bs, Kv, hd)) for _ in range(2)]
+    table = np.full((B, MB), NB + 5, np.int32)
+    perm = RNG.permutation(NB)
+    for b, n in enumerate(lengths):
+        for d, x in zip(dense, new):
+            d[b, :n] = RNG.normal(size=(n, Kv, hd))
+            if n < S:
+                d[b, n] = np.asarray(x[b], np.float64)
+        for j in range(-(-n // bs)):
+            page = perm[b * MB + j]
+            table[b, j] = page
+            for d, pool in zip(dense, pools):
+                pool[L - 1, page, :min(bs, n - j * bs)] = \
+                    d[b, j * bs:min((j + 1) * bs, n)]
+    per_head = [jnp.asarray(d.transpose(0, 2, 1, 3).reshape(B * Kv, S, hd),
+                            dtype) for d in dense]
+    kl = jnp.repeat(jnp.asarray(lengths, jnp.int32) + 1, Kv)
+    args = (q, new[0], new[1], jnp.asarray(pools[0], dtype),
+            jnp.asarray(pools[1], dtype), jnp.asarray(table),
+            jnp.asarray(lengths, jnp.int32), jnp.int32(L - 1))
+    return args, per_head[0], per_head[1], kl
+
+
+def _check_paged(args, k, v, kl, dtype, **kw):
+    """Kernel and oracle both against the dense reference."""
+    q = args[0]
+    B, Hp, hd = q.shape
+    Kv = args[1].shape[1]
+    want = ref.decode_attention_ref(q.reshape(B * Kv, Hp // Kv, hd), k, v,
+                                    kl, **kw).reshape(B, Hp, hd)
+    window = kw.pop("window", None)
+    window = None if window is None else jnp.int32(window)
+    for use_pallas in (False, True):
+        got = ops.decode_attention_paged(*args, window, use_pallas=use_pallas,
+                                         **kw)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   **_tol(dtype))
+
+
 @pytest.mark.parametrize("G,bs,MB", [(1, 16, 4), (4, 8, 8), (7, 32, 3)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_paged_sweep(G, bs, MB, dtype):
     """Paged kernel + its oracle vs the dense reference: pages land in a
     permuted physical pool full of junk, with sentinel table entries past
     each sequence's live pages."""
-    BKv, hd = 3, 32
-    S = MB * bs
-    q = jnp.asarray(RNG.normal(size=(BKv, G, hd)), dtype)
-    k = RNG.normal(size=(BKv, S, hd))
-    v = RNG.normal(size=(BKv, S, hd))
-    kl = RNG.integers(1, S, BKv).astype(np.int32)
-    NB = BKv * MB + 3
-    perm = RNG.permutation(NB)[:BKv * MB]
-    k_pool = RNG.normal(size=(NB, bs, hd))         # junk everywhere else
-    v_pool = RNG.normal(size=(NB, bs, hd))
-    table = np.full((BKv, MB), NB + 5, np.int32)   # sentinel = OOB
-    for b in range(BKv):
-        for j in range(MB):
-            if j * bs < kl[b]:                     # only live pages mapped
-                p = perm[b * MB + j]
-                table[b, j] = p
-                k_pool[p] = k[b, j * bs:(j + 1) * bs]
-                v_pool[p] = v[b, j * bs:(j + 1) * bs]
-    k_pool = jnp.asarray(k_pool, dtype)
-    v_pool = jnp.asarray(v_pool, dtype)
-    table, kl = jnp.asarray(table), jnp.asarray(kl)
-    want = ref.decode_attention_ref(q, jnp.asarray(k, dtype),
-                                    jnp.asarray(v, dtype), kl)
-    for use_pallas in (False, True):
-        got = ops.decode_attention_paged(q, k_pool, v_pool, table, kl,
-                                         use_pallas=use_pallas)
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32),
-                                   **_tol(dtype))
+    lengths = RNG.integers(1, MB * bs, 3).tolist()
+    _check_paged(*_paged_case(lengths, G, 2, 32, bs, MB, dtype), dtype)
+
+
+# the benchmark cells' head shapes, shallow and short: qwen2-7b (GQA, 7
+# query heads on each of 4 KV heads) and opt-30b (MHA, 56 heads); pages
+# of 16 tokens, head size 128. MB is past one loop step of pages where
+# the step is short (qwen2-7b in f32: 16 pages; opt-30b: 2 or 1).
+CELL_HEADS = [("qwen2-7b", 7, 4, 20), ("opt-30b", 1, 56, 5)]
+
+
+@pytest.mark.parametrize("name,G,Kv,MB", CELL_HEADS,
+                         ids=[c[0] for c in CELL_HEADS])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_at_cell_head_shapes(name, G, Kv, MB, dtype):
+    """Slots of no history, one token, exactly one page, a full table
+    with room for the new token, and a full table that drops it."""
+    bs = 16
+    lengths = [0, 1, bs, MB * bs - 1, MB * bs]
+    _check_paged(*_paged_case(lengths, G, Kv, 128, bs, MB, dtype), dtype)
+
+
+@pytest.mark.parametrize("name,G,Kv,MB", CELL_HEADS,
+                         ids=[c[0] for c in CELL_HEADS])
+@pytest.mark.parametrize("kw", [dict(window=37), dict(softcap=30.0),
+                                dict(window=1, softcap=30.0)],
+                         ids=["window", "softcap", "window1-softcap"])
+def test_paged_decode_window_and_softcap(name, G, Kv, MB, kw):
+    """A sliding window skips the pages before it (a window of one sees
+    only the new token); a softcap bounds every score."""
+    lengths = [3, 50, MB * 16 - 1]
+    _check_paged(*_paged_case(lengths, G, Kv, 128, 16, MB, jnp.float32),
+                 jnp.float32, **dict(kw))
+
+
+def test_paged_decode_head_parallel_matches_single_device():
+    """Under a 4-way head sharding the kernel runs per device on its own
+    KV heads (shard_map) and agrees with the single-device launch."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("model",))
+    args, _, _, _ = _paged_case([0, 21, 79], 7, 4, 128, 16, 5, jnp.float32)
+    whole = ops.decode_attention_paged(*args, use_pallas=True)
+    split = ops.decode_attention_paged(
+        *args, use_pallas=True,
+        head_sharding=NamedSharding(mesh, P(None, "model", None)))
+    assert split.sharding.spec[1] == "model"
+    np.testing.assert_allclose(np.asarray(split), np.asarray(whole),
+                               **_tol(jnp.float32))
 
 
 @pytest.mark.parametrize("Bt,I,N", [(1, 64, 16), (2, 128, 8), (3, 96, 4)])

@@ -1,5 +1,7 @@
 """Paged KV-cache backend: greedy equivalence vs contiguous (restore,
-mid-stream pause/resume, retire), allocator edge cases (exhaustion ->
+mid-stream pause/resume, retire), the paged decode step (against the
+contiguous step and a full forward, on the gather path and on the Pallas
+kernel; donation; sentinel writes), allocator edge cases (exhaustion ->
 queue backpressure, page reuse after eviction), occupancy gauges."""
 import jax
 import jax.numpy as jnp
@@ -133,6 +135,129 @@ def test_paged_multi_round_restoration_matches_ground_truth(setup):
         lg, cache = model.decode_step(params, cache, nt)
         nt = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
     assert g2 == want
+
+
+# ---------------------------------------------------- the decode step
+@pytest.fixture(params=["gather", "kernel"])
+def decode_path(request, monkeypatch):
+    """Which branch the paged step takes: off the TPU it gathers each
+    layer's logical layout; ``kernel`` steers it onto its TPU branch,
+    the Pallas kernel, run in interpret mode. Traces made under the
+    steer are dropped afterwards, so no later step reuses them."""
+    traced = []
+    if request.param == "kernel":
+        from repro.kernels import ops
+        kernel = ops.paged_decode_attention_pallas
+
+        def interpreted(*a, **k):
+            traced.append(1)
+            return kernel(*a, **dict(k, interpret=True))
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+        monkeypatch.setattr(ops, "paged_decode_attention_pallas",
+                            interpreted)
+    jax.clear_caches()
+    yield request.param
+    jax.clear_caches()
+    assert bool(traced) == (request.param == "kernel")
+
+
+def _paged_twin(setup, prompts, *, bs=16, MB=8, seed=0):
+    """The prompts prefilled into a contiguous cache and into a paged one
+    whose pages are permuted through a junk-filled pool, with sentinel
+    table entries past the pages each slot can reach."""
+    cfg, model, params = setup
+    rng = np.random.default_rng(seed)
+    B, S = len(prompts), MB * bs
+    L, Kv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim_
+    NB = B * MB + 5
+    cont = model.init_cache(B, S)
+    pools = [rng.normal(size=(L, NB, bs, Kv, hd)).astype(np.float32)
+             for _ in range(2)]
+    table = np.full((B, MB), NB, np.int32)
+    perm = rng.permutation(NB)
+    first = []
+    for b, p in enumerate(prompts):
+        pre = model.prefill(params, {"tokens": jnp.asarray(p)[None]})
+        n = len(p)
+        for name, kv, pool in zip(("k", "v"), pre["kv"], pools):
+            cont[name] = cont[name].at[:, b, :n].set(kv[:, 0])
+            for j in range(-(-n // bs)):
+                pool[:, perm[b * MB + j], :min(bs, n - j * bs)] = \
+                    np.asarray(kv[:, 0, j * bs:(j + 1) * bs])
+        table[b, :MB - 1] = perm[b * MB:b * MB + MB - 1]
+        first.append(int(jnp.argmax(pre["logits"][0, -1])))
+    lengths = jnp.asarray([len(p) for p in prompts], jnp.int32)
+    cont["lengths"] = lengths
+    paged = {"k_pool": jnp.asarray(pools[0]), "v_pool": jnp.asarray(pools[1]),
+             "block_table": jnp.asarray(table), "lengths": lengths}
+    return cont, paged, np.asarray(first, np.int32)[:, None]
+
+
+def test_paged_step_matches_contiguous_and_full_forward(setup, decode_path):
+    """Four steps of two slots (37 tokens, and exactly one page) through
+    the paged step, against the contiguous step and against a float32
+    forward over each whole sequence, whose last position is the new
+    token attending to itself. The gather path is the contiguous step's
+    arithmetic and matches it bitwise; the kernel sums in another order
+    and stays within 1e-4 of both."""
+    cfg, model, params = setup
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (37, 16)]
+    cont, paged, tok = _paged_twin(setup, prompts)
+    cont_step = jax.jit(lambda p, c, t: model.decode_step(p, c, t))
+    paged_step = jax.jit(lambda p, c, t: model.decode_step_paged(p, c, t))
+    seqs = [list(p) for p in prompts]
+    for _ in range(4):
+        for b in range(2):
+            seqs[b].append(int(tok[b, 0]))
+        lg_c, cont = cont_step(params, cont, jnp.asarray(tok))
+        lg_p, paged, _ = paged_step(params, paged, jnp.asarray(tok))
+        if decode_path == "gather":
+            np.testing.assert_array_equal(np.asarray(lg_p), np.asarray(lg_c))
+        else:
+            np.testing.assert_allclose(np.asarray(lg_p), np.asarray(lg_c),
+                                       rtol=1e-4, atol=1e-4)
+        for b in range(2):
+            full = model.prefill(params, {"tokens": jnp.asarray(seqs[b])[None]})
+            np.testing.assert_allclose(np.asarray(lg_p[b, 0]),
+                                       np.asarray(full["logits"][0, -1]),
+                                       rtol=1e-4, atol=1e-4)
+        tok = np.asarray(jnp.argmax(lg_c[:, 0], -1), np.int32)[:, None]
+    np.testing.assert_array_equal(np.asarray(paged["lengths"]),
+                                  [37 + 4, 16 + 4])
+
+
+def test_decode_donates_the_pool(setup):
+    """The backend's step takes the cache as donated: the pools it was
+    handed are gone after the step, and the step's own are live."""
+    cfg, model, params = setup
+    b = PagedBackend(model, max_batch=2, max_seq=64, block_size=16)
+    assert b.reserve(0, 20)
+    b.set_length(0, 5)
+    old = (b.cache["k_pool"], b.cache["v_pool"])
+    b.decode(params, jnp.zeros((2, 1), jnp.int32))
+    assert all(x.is_deleted() for x in old)
+    assert not any(b.cache[n].is_deleted() for n in ("k_pool", "v_pool"))
+    assert b.get_lengths().tolist() == [6, 1]
+
+
+def test_sentinel_writes_leave_the_pool_unchanged(setup, decode_path):
+    """A slot whose table is full and an unoccupied slot (a sentinel row)
+    both write their new token to a sentinel address: the step drops
+    both writes and the pool comes back as it went in."""
+    cfg, model, params = setup
+    b = PagedBackend(model, max_batch=2, max_seq=64, block_size=16)
+    assert b.reserve(0, 64)                        # the whole table row
+    rng = np.random.default_rng(6)
+    for name in ("k_pool", "v_pool"):
+        b.cache[name] = jnp.asarray(
+            rng.normal(size=b.cache[name].shape).astype(np.float32))
+    b.set_length(0, 64)
+    before = {n: np.asarray(b.cache[n]) for n in ("k_pool", "v_pool")}
+    b.decode(params, jnp.zeros((2, 1), jnp.int32))
+    for n, want in before.items():
+        np.testing.assert_array_equal(np.asarray(b.cache[n]), want)
 
 
 # ------------------------------------------- exhaustion / backpressure

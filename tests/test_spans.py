@@ -102,6 +102,32 @@ def test_counters_over_save_evict_restore(setup):
     assert m.save_wall_sum > 0.0
 
 
+def test_decode_kv_bytes_counts_live_history(setup):
+    """Two sessions decode side by side: each step adds the tokens every
+    slot holds before it, times one token's K+V bytes over all layers."""
+    cfg, _, _ = setup
+    engine = engine_for(setup)
+    held = []
+    inner = engine.kv.decode
+
+    def decode(params, tokens):
+        held.append(int(engine.kv.get_lengths().sum()))
+        return inner(params, tokens)
+    engine.kv.decode = decode
+    rng = np.random.default_rng(4)
+    for sid, n in (("a", 20), ("b", 12)):
+        engine.submit(Request(sid, rng.integers(0, cfg.vocab_size, n)
+                              .astype(np.int32), max_new_tokens=4))
+    engine.run()
+    engine.close()
+    m = engine.metrics
+    per_token = cfg.n_layers * 2 * cfg.n_kv_heads * cfg.head_dim_ * 4
+    assert engine.kv.kv_token_bytes == per_token
+    assert m.decode_steps == len(held) > 0
+    assert max(held) >= 20 + 12                    # both slots at once
+    assert m.decode_kv_bytes == sum(held) * per_token
+
+
 def test_restore_counters_by_method():
     """A tiny OPT session saved under a plan that mixes all three methods,
     then restored by the engine: each by-method counter is exact. The
@@ -182,7 +208,8 @@ COUNTERS = ("queue_wait_sum", "admitted", "prefill_wall_sum",
             "saved_tokens", "save_d2h_bytes", "restore_read_wall",
             "restore_upload_wall", "restore_write_wall", "restore_h2d_bytes",
             "restore_hidden_layer_tokens", "restore_kv_layer_tokens",
-            "restore_recompute_layer_tokens", "restore_hidden_h2d_bytes")
+            "restore_recompute_layer_tokens", "restore_hidden_h2d_bytes",
+            "decode_kv_bytes")
 
 
 @pytest.mark.parametrize("name", COUNTERS)
