@@ -43,8 +43,8 @@ for r in trace:
     engine.run()
     seq = engine.sessions[r.session_id]
     print(f"{r.session_id}: +{n_in} prompt, {len(seq.generated)} generated, "
-          f"history {seq.history_len}, restore(sim) "
-          f"{seq.restore_sim * 1e3:.3f} ms, TTFT(wall) {seq.ttft_wall:.3f} s")
+          f"history {seq.history_len}, restore(wall) "
+          f"{seq.restore_wall * 1e3:.3f} ms, TTFT(wall) {seq.ttft_wall:.3f} s")
 
 m = engine.metrics
 print(f"\n{len(m.ttft_wall)} requests; {m.restored_tokens} tokens restored; "

@@ -25,9 +25,8 @@ class HardwareProfile:
     # fixed per-device-dispatch cost charged to every compute-stream task
     # in the restoration replay (kernel launch + host-side framework
     # overhead). 0.0 keeps the paper's pure-bandwidth/FLOPs model; the
-    # grouped restoration path amortizes this over group_size layers —
-    # see benchmarks/bench_restore_batch.py for the knob's measurable
-    # effect on makespan.
+    # grouped restoration path amortizes this over group_size layers
+    # (tests/test_restore_batch.py checks the effect on makespan).
     dispatch_overhead: float = 0.0
     # tensor-parallel mesh width the restoration compute runs SPMD over
     # (DESIGN.md §16): projection FLOPs divide across the shards, the
@@ -55,8 +54,8 @@ class HardwareProfile:
         (shared PCIe lanes, filesystem overhead on the SSDs, sustained
         vs peak GEMM throughput). Every profile here is a guess until
         the online profiler (core/profiler.py) measures it; derated
-        copies stand in for "what the machine actually does" in
-        calibration tests and bench_sched."""
+        copies stand in for "what the machine actually does" in the
+        calibration tests."""
         return dataclasses.replace(
             self, name=self.name + "-derated",
             storage_bw=self.storage_bw * storage,

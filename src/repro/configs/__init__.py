@@ -1,7 +1,8 @@
 """Architecture registry: ``--arch <id>`` resolution.
 
 Assigned archs use their public ids (hyphenated); the paper's own models are
-also registered for the benchmark suite.
+also registered (the default ``--arch`` of ``launch/serve.py`` and of many
+tests).
 """
 from __future__ import annotations
 

@@ -1,7 +1,6 @@
 """The paper's own evaluation models (§6): Llama2-7B/13B, OPT-30B.
 
-These drive the analytical replications of the paper's figures
-(benchmarks/). All three are MHA — the paper's primary regime.
+All three are MHA — the paper's primary regime.
 """
 from repro.config.arch import ArchConfig
 

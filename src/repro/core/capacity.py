@@ -178,7 +178,7 @@ class RestoreCostAwareEviction(EvictionPolicy):
     restoration covers ``total_len - 1`` tokens (the last sampled token
     is re-fed, not restored). Keeps the expensive long-history sessions
     resident, so the restore traffic the eviction churn generates is
-    minimized — the knob ``bench_capacity`` compares against LRU."""
+    minimized."""
 
     name = "restore_cost"
 

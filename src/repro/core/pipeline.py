@@ -14,8 +14,8 @@ Stream rules (paper §4.1):
   * a layer's projection starts when its fetch has completed and the
     compute stream is free.
 
-``simulate`` returns per-stream busy/idle so benchmarks can report bubble
-fractions (Fig 12) and the TTFT decomposition (Figs 9/10).
+``simulate`` returns per-stream busy/idle: the bubble fractions (Fig 12) and
+the TTFT decomposition (Figs 9/10).
 """
 from __future__ import annotations
 

@@ -370,7 +370,7 @@ def choose_group_size(cfg, hw, n_tokens: int, methods: Sequence[str], *,
     ``fetch_aligned``, the non-uniform fetch-completion partition — and
     take the makespan argmin. The same group-aware cost model the
     executor's timeline and ``capacity.restore_makespan`` use, so the
-    planner and the bake-off metric cannot disagree. Ties prefer fewer
+    planner and the executor's timeline cannot disagree. Ties prefer fewer
     groups (equal modeled makespan, strictly fewer real device
     dispatches). Returns an int (uniform width) or a tuple of widths
     (non-uniform partition).
@@ -667,7 +667,7 @@ def build_param_pack(model, params, tp_ctx=None)\
 
 # number of times the grouped projection has been TRACED (== compiled):
 # the body below runs once per compilation, so this is the recompile
-# counter the bucketing regression test and bench_restore_batch read.
+# counter the bucketing regression tests read.
 _PROJECTION_TRACES = [0]
 
 
@@ -765,7 +765,8 @@ class RestorationExecutor:
     batched upload + one stacked projection + one grouped sink write per
     group. ``dispatch_count`` tallies the device dispatches the restore
     issued; ``project_wall`` the wall seconds inside projection calls —
-    both surfaced by bench_restore_batch. ``read_wall``, ``upload_wall``
+    the tests read the first, ``/metrics`` the second (summed over
+    restores as ``restore_project_wall``). ``read_wall``, ``upload_wall``
     and ``write_wall`` are the seconds of the executor's store-read,
     host->device and cache-write spans (core/trace.py), ``h2d_bytes``
     the bytes it handed to the device (``hidden_h2d_bytes`` of them the

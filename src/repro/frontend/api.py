@@ -3,7 +3,7 @@
 ``FrontDoor.handle(method, path, body)`` implements
 ``/v1/chat/completions`` and ``/v1/completions`` (plus ``/v1/models``,
 ``/healthz``, ``/metrics``) against the engine pump and session router —
-no sockets anywhere, so tests and the SLO harness drive the exact
+no sockets anywhere, so tests and ``bench/`` drive the exact
 request path the HTTP binding (frontend/server.py) serves, byte for
 byte. ``handle`` returns ``(status, payload)``; a streaming request's
 payload is an async generator of SSE-framed strings

@@ -44,8 +44,8 @@ class Subscription:
     """Per-request fan-out endpoint. The pump posts ``("token", id)``,
     ``("pause", None)`` and a final ``("finish", reason)`` event; with an
     event loop attached the same events also land in ``queue`` for async
-    consumption. Timestamps are perf_counter at post time — the SLO
-    harness reads TTFT/TBT straight from here."""
+    consumption. Timestamps are perf_counter at post time, so ``ttft`` and
+    ``tbt`` are read straight from here."""
 
     def __init__(self, request: Request,
                  loop: Optional[asyncio.AbstractEventLoop] = None):

@@ -92,15 +92,11 @@ class RouterBusy(RuntimeError):
 class SessionRouter:
     def __init__(self, engine=None, *, n_slots: int = 8,
                  block_size: int = 16, reuse_threshold: float = 0.5,
-                 steer: bool = True, max_stored: int = 64):
+                 max_stored: int = 64):
         self.engine = engine
         self.n_slots = int(n_slots)
         self.block_size = int(block_size)
         self.reuse_threshold = float(reuse_threshold)
-        # steer=False is the route-blind baseline the SLO harness
-        # compares against: every request lands on a fresh session and
-        # pays its full history as prefill
-        self.steer = bool(steer)
         self.slots = [RouterSlot(i) for i in range(self.n_slots)]
         self.stored: Dict[str, StoredSession] = {}
         self.max_stored = int(max_stored)
@@ -232,10 +228,6 @@ class SessionRouter:
         with self._lock:
             self._clock += 1
             self.lookups += 1
-            if not self.steer:
-                self.fresh += 1
-                return RouteDecision(self._fresh_id(), tokens, "fresh",
-                                     tokens)
             # 1. exact conversation-id binding
             slot = (self._by_conv.get(conversation_id)
                     if conversation_id else None)

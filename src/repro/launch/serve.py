@@ -118,8 +118,7 @@ def make_parser() -> argparse.ArgumentParser:
                         "dedup / session forking")
     p.add_argument("--metrics-json", default=None, metavar="PATH",
                    help="dump the final EngineMetrics counters/gauges as "
-                        "JSON to PATH on exit (what bench_slo and CI "
-                        "consume instead of scraping printed text)")
+                        "JSON to PATH on exit (the dict /metrics serves)")
     p.add_argument("--serve-http", action="store_true",
                    help="serve the engine through the front door "
                         "(DESIGN.md §14): OpenAI-compatible HTTP API + "
@@ -252,7 +251,7 @@ def main() -> None:
         for s in range(args.sessions):
             seq = engine.sessions[f"user{s}"]
             print(f"round {rnd} user{s}: {len(seq.generated)} tokens, "
-                  f"restore_sim {seq.restore_sim * 1e3:.2f} ms, "
+                  f"restore_wall {seq.restore_wall * 1e3:.2f} ms, "
                   f"ttft_wall {seq.ttft_wall:.3f} s")
     m = engine.metrics
     print(f"\nrestored {m.restored_tokens} tokens over "

@@ -94,11 +94,6 @@ class EngineMetrics:
     ttft_wall_restored: List[float] = dataclasses.field(default_factory=list)
     ttft_wall_cold: List[float] = dataclasses.field(default_factory=list)
     tbt_wall: List[float] = dataclasses.field(default_factory=list)
-    # every completed restoration's simulated makespan — includes resumes
-    # of mid-stream-evicted sessions, not only first tokens; the resume
-    # subset is the victim-selection bake-off metric in bench_capacity
-    restore_sim_all: List[float] = dataclasses.field(default_factory=list)
-    restore_sim_resume: List[float] = dataclasses.field(default_factory=list)
     preemptions: int = 0                # mid-stream evictions (PAUSED)
     restored_tokens: int = 0
     decode_steps: int = 0
@@ -215,9 +210,9 @@ class EngineMetrics:
 
     def to_dict(self) -> dict:
         """JSON-serializable dump of every counter/gauge; per-request
-        populations (TTFT/TBT/makespans) summarized as n/mean/p50/p99/max.
-        This is what ``serve.py --metrics-json`` writes and what the SLO
-        harness consumes — benches never scrape printed text."""
+        populations (TTFT/TBT) summarized as n/mean/p50/p99/max.
+        This is what ``serve.py --metrics-json`` writes and ``/metrics``
+        serves."""
         out = {}
         for f in dataclasses.fields(self):
             v = getattr(self, f.name)
@@ -546,7 +541,6 @@ class InferenceEngine:
                 self._prefetch.pop(sid, None)
                 seq.restored = True
                 seq.history_len = n_man
-                seq.restore_sim = 0.0
                 seq.restore_wall = 0.0
                 self.kv.set_length(slot, n_man)
                 seq.phase = Phase.PREFILL
@@ -830,12 +824,8 @@ class InferenceEngine:
                 seq.executor = None
                 seq.restored = True
                 seq.history_len = ex.n_tokens
-                seq.restore_sim = ex.timeline().makespan
                 seq.restore_wall = ex.wall_time
                 m.restored_tokens += ex.n_eff
-                m.restore_sim_all.append(seq.restore_sim)
-                if seq.pending_from_gen:       # resume of a paused session
-                    m.restore_sim_resume.append(seq.restore_sim)
                 m.restore_project_wall += ex.project_wall
                 m.restore_wall_sum += ex.wall_time
                 m.restore_read_wall += ex.read_wall

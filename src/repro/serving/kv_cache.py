@@ -85,8 +85,8 @@ def _colocate(val, buf):
 
 @dataclasses.dataclass
 class OccupancyStats:
-    """Gauges for EngineMetrics / bench_paged: how much of the reserved
-    cache capacity holds live tokens."""
+    """Gauges for EngineMetrics (and so ``/metrics``): how much of the
+    reserved cache capacity holds live tokens."""
 
     live_tokens: int            # tokens of occupied slots (sum of lengths)
     reserved_tokens: int        # capacity handed out to occupied slots

@@ -34,8 +34,7 @@ class Request:
     frames: Optional[np.ndarray] = None
     # arrival stamps. The engine fills both at submit() UNLESS the caller
     # pre-stamped them — the front door (frontend/pump.py) stamps
-    # arrival_time at ingress so TTFT includes its queueing, and the SLO
-    # harness keys per-request accounting off arrival_step ordering.
+    # arrival_time at ingress so TTFT includes its queueing.
     arrival_time: float = 0.0                # perf_counter at arrival
     arrival_step: int = -1                   # engine step_count at arrival
     request_id: int = dataclasses.field(default_factory=lambda: next(_ids))
@@ -72,7 +71,6 @@ class SequenceState:
     restored: bool = False                   # completed a restoration
     # metrics
     ttft_wall: Optional[float] = None
-    restore_sim: float = 0.0                 # simulated restoration seconds
     restore_wall: float = 0.0
     first_token_step: Optional[int] = None
 

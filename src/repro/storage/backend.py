@@ -5,8 +5,8 @@ Three tiers, all exposing the same byte-level API:
   DRAMBackend      — host memory (paper's cloud-server fallback).
   SimulatedSSD     — host memory + a bandwidth/latency model of one NVMe
                      device (PM9A3 by default). Reads/writes advance a
-                     device-local clock so benchmarks measure contention and
-                     striping gains without real disks.
+                     device-local clock, so the restore planner sees
+                     contention and striping gains without real disks.
   FileBackend      — real files (persistence across engine restarts —
                      the serving fault-tolerance path).
 

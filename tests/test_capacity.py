@@ -73,14 +73,24 @@ def test_preemption_equivalence_8_sessions_2_slots(setup, eviction):
     want = {f"s{i}": ref.result(f"s{i}") for i in range(8)}
     ref.close()
 
-    eng, _ = fresh_engine(setup, max_batch=2, preempt_quantum=3,
-                          eviction=eviction)
+    eng, mgr = fresh_engine(setup, max_batch=2, preempt_quantum=3,
+                            eviction=eviction)
+    # every executor the engine starts, prefetched or admitted; only
+    # the admitted ones run to completion
+    begun = []
+    begin = mgr.begin_restore
+
+    def counted(*a, **k):
+        begun.append(begin(*a, **k))
+        return begun[-1]
+    mgr.begin_restore = counted
     for i, p in enumerate(prompts):
         eng.submit(Request(f"s{i}", p, max_new_tokens=5))
     eng.run()
     got = {f"s{i}": eng.result(f"s{i}") for i in range(8)}
     assert eng.metrics.preemptions > 0            # eviction actually ran
-    assert len(eng.metrics.restore_sim_all) == eng.metrics.preemptions
+    # one completed restoration per mid-stream eviction
+    assert sum(ex.done for ex in begun) == eng.metrics.preemptions
     assert all(s.phase.value == "done" for s in eng.sessions.values())
     assert got == want
     eng.close()

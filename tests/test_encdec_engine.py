@@ -196,9 +196,9 @@ def test_cross_restore_tasks_modeled(setup):
 
 
 def test_engine_restore_timeline_includes_cross(setup):
-    """Serving-path restore of an enc-dec session reports a makespan ≥
-    the cross-only lower bound (the engine's restore_sim and the
-    analytic replay share one task graph)."""
+    """Serving-path restore of an enc-dec session plans a makespan ≥
+    the cross-only lower bound: the executor the engine restores with
+    carries the cross tasks in its task graph."""
     cfg, model, params = setup
     eng, mgr = fresh_engine(setup)
     p = np.arange(10, dtype=np.int32)
@@ -206,11 +206,13 @@ def test_engine_restore_timeline_includes_cross(setup):
     eng.run()
     eng.submit(Request("t", np.arange(4, dtype=np.int32), max_new_tokens=2))
     eng.run()
-    seq = eng.sessions["t"]
-    assert seq.restored
+    assert eng.sessions["t"].restored
     from repro.core.restoration import cross_restore_times
     ct = cross_restore_times(mgr, 16)
-    assert seq.restore_sim >= ct.compute
+    ex = mgr.begin_restore(params, "t")
+    kinds = [t.kind for t in ex.tasks]
+    assert kinds.count("io_enc") == 1 and kinds.count("project_cross") == 1
+    assert ex.predicted_makespan >= ct.compute
     eng.close()
 
 

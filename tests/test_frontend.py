@@ -128,17 +128,6 @@ def test_router_reuse_threshold_rejects_short_match():
     assert d2.kind == "fresh"
 
 
-def test_router_blind_never_steers():
-    r = _chain_router(steer=False)
-    p = np.arange(12, dtype=np.int32)
-    d1 = r.route(p, "conv-a")
-    r.complete(d1, [1, 2])
-    d2 = r.route(np.concatenate([p, [1, 9]]).astype(np.int32), "conv-a")
-    assert d1.kind == d2.kind == "fresh"
-    assert d2.session_id != d1.session_id
-    assert r.stats()["hit_rate"] == 0.0
-
-
 def test_router_busy_conflict_and_cancel():
     r = _chain_router()
     p = np.arange(10, dtype=np.int32)
